@@ -551,6 +551,9 @@ def main(argv=None) -> int:
         "writes trace.json + fitted_params.json next to the results JSON",
     )
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import configure_compile_cache
+
+    print(f"# compile cache: {configure_compile_cache()}", file=sys.stderr)
     rows = SMOKE_ROWS if args.smoke else args.rows
     out_path = args.out
     if out_path is None and args.smoke:
@@ -612,6 +615,9 @@ def main(argv=None) -> int:
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text(json.dumps(payload, indent=2))
         print(f"# results JSON: {p}", file=sys.stderr)
+    if failures:
+        print(f"# failed sections: {', '.join(failures)}", file=sys.stderr)
+        return 1
     return 0
 
 

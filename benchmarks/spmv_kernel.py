@@ -173,7 +173,7 @@ def measured_rows(rows: int):
     # -- CPU reference path on the fine level ------------------------------
     A = hierarchy_for(min(rows, 65_536)).levels[0].A
     t_flat, t_blocked, bell = _check_and_time(
-        A, block_cols=512, backend_name="reference", iters=10, warmup=2
+        A, block_cols=512, backend_name="xla", iters=10, warmup=2
     )
     geom = (f"rows={A.nrows}|buckets={bell.n_buckets}"
             f"|bucket_k={bell.K}")

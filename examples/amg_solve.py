@@ -47,6 +47,9 @@ def main():
 
     import jax
 
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     jax.config.update("jax_enable_x64", True)
 
     from repro.amg import DistributedHierarchy, build_hierarchy, diffusion_2d, \

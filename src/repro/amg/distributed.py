@@ -175,6 +175,9 @@ class DistributedHierarchy:
         # rebuild that produced this instance (None for a first setup)
         self._host: Optional[Hierarchy] = None
         self.last_resize = None
+        # the last solve's final iterate as the devices hold it: [P, pad],
+        # one row block per device of the mesh
+        self.x_device = None
         self._build_device_fns()
 
     # ------------------------------------------------------------- setup
@@ -411,7 +414,7 @@ class DistributedHierarchy:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as PSpec
 
-        from ..compat import shard_map
+        from jax import shard_map
         from ..core import dense_round_runner
         from ..sparse.partition import partitioned_to_global
 
@@ -473,11 +476,14 @@ class DistributedHierarchy:
 
         spec = PSpec(self.axis_name)
         return shard_map(per_device, mesh=self.mesh, in_specs=(spec,),
-                         out_specs=spec, check_rep=False)
+                         out_specs=spec, check_vma=False)
 
     def _build_device_fns(self) -> None:
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec as PSpec
 
+        blocks = NamedSharding(self.mesh, PSpec(self.axis_name))
+        self._dinv = [jax.device_put(lv.dinv, blocks) for lv in self.levels]
         self._Amv = [self._bind(lv.A) for lv in self.levels]
         self._Rmv = [
             self._bind(lv.R) if lv.R is not None else None
@@ -490,15 +496,59 @@ class DistributedHierarchy:
         self._coarse_fn = (
             self._bind_coarse() if self.coarse_gather != "off" else None
         )
-        self._step = jax.jit(self._make_step())
+        self._step: Optional[Callable] = None
+        self._consts: list = []
+
+    def step_program(self) -> Tuple[Callable, list]:
+        """The V-cycle step as one jitted program, and its operands.
+
+        Returns ``(step, consts)`` with ``step(consts, x, b) -> (x + V(b -
+        Ax), ||b - Ax||)`` over ``[P, pad]`` block vectors.  Operators,
+        halo index maps and smoother scalings enter the program as the
+        arguments ``consts``, not as literals: a fine level of 2^20 rows
+        would otherwise write about 10^8 bytes of constants into the
+        program, which slows its compilation and keys the compile cache on
+        the matrix values.
+        """
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as PSpec
+
+        vec = jax.ShapeDtypeStruct(
+            (self.topo.n_procs, self.levels[0].pad), self.dtype,
+            sharding=NamedSharding(self.mesh, PSpec(self.axis_name)),
+        )
+        closed = jax.make_jaxpr(self._make_step())(vec, vec)
+        jaxpr = closed.jaxpr
+
+        def amg_vcycle_step(consts, x, b):
+            return tuple(jax.core.eval_jaxpr(jaxpr, consts, x, b))
+
+        return jax.jit(amg_vcycle_step), list(closed.consts)
+
+    def _device_step(self) -> Callable:
+        """:meth:`step_program`, built on first use, with its operands
+        placed in ``self._consts``."""
+        if self._step is not None:
+            return self._step
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as PSpec
+
+        self._step, consts = self.step_program()
+        replicated = NamedSharding(self.mesh, PSpec())
+        # block-sharded operands are already placed; host constants traced
+        # into the step (scalars, the dense coarse operator) are replicated
+        self._consts = [
+            c if isinstance(c, jax.Array) and c.committed
+            else jax.device_put(c, replicated)
+            for c in consts
+        ]
+        return self._step
 
     def _cheby(self, k: int, x, b, degree: int):
         """Chebyshev smoother — same arithmetic as the host ``chebyshev``."""
         lv = self.levels[k]
         Amv = self._Amv[k]
-        import jax.numpy as jnp
-
-        dinv = jnp.asarray(lv.dinv)
+        dinv = self._dinv[k]
         rho = lv.rho
         upper = 1.1 * rho
         lower = 0.30 * rho
@@ -560,20 +610,24 @@ class DistributedHierarchy:
         the old geometry is re-packed under the new blocking and the
         contraction continues where it left off.
         """
-        import jax.numpy as jnp
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as PSpec
 
         lv0 = self.levels[0]
-        bg = jnp.asarray(
-            pack_vector(lv0.A.part.col_offsets, lv0.pad, b.astype(self.dtype))
-        )
-        if x0 is None:
-            x = jnp.zeros_like(bg)
-        else:
-            x = jnp.asarray(
+        blocks = NamedSharding(self.mesh, PSpec(self.axis_name))
+
+        def place(v):
+            # one row block per device, so no call moves a whole vector
+            return jax.device_put(
                 pack_vector(lv0.A.part.col_offsets, lv0.pad,
-                            np.asarray(x0).astype(self.dtype))
+                            np.asarray(v).astype(self.dtype)),
+                blocks,
             )
+
+        bg = place(b)
+        x = place(np.zeros(len(b)) if x0 is None else x0)
         nb = max(float(np.linalg.norm(b)), 1e-300)
+        step = self._device_step()
         hist: List[float] = []
         with _OBS.span("amg/solve", n=lv0.n, tol=tol,
                        max_iters=max_iters) as sp:
@@ -581,13 +635,14 @@ class DistributedHierarchy:
                 # the float() is the device sync: the iteration span
                 # covers the whole V-cycle, not just its dispatch
                 with _OBS.span("amg/vcycle_iter", iter=it):
-                    x_new, rn = self._step(x, bg)
+                    x_new, rn = step(self._consts, x, bg)
                     rel = float(rn) / nb
                 hist.append(rel)
                 if rel < tol:
                     break
                 x = x_new
             sp.set(iters=len(hist), final_rel=hist[-1] if hist else 0.0)
+        self.x_device = x
         return unpack_vector(lv0.A.part.offsets, np.asarray(x)), hist
 
     # ------------------------------------------------------------ elastic
@@ -736,6 +791,8 @@ class DistributedHierarchy:
         span attributes) — how a production solve keeps feeding
         calibration without threading a tracer through every call.
         """
+        from jax.sharding import NamedSharding, PartitionSpec as PSpec
+
         from ..core.collectives import time_executor
 
         out = []
@@ -752,6 +809,7 @@ class DistributedHierarchy:
                     dtype=self.dtype,
                     iters=iters,
                     warmup=warmup,
+                    sharding=NamedSharding(self.mesh, PSpec(self.axis_name)),
                 )
                 if tracer is not None:
                     tracer.record_plan(lv.A.coll.plan, secs,

@@ -179,14 +179,14 @@ def make_executor(
             idx_arrays.append(rnd.scatter)
 
     spec = P(axis_name)
-    from ..compat import shard_map
+    from jax import shard_map
 
     fn = shard_map(
         per_device,
         mesh=mesh,
         in_specs=(spec,) * (1 + len(idx_arrays)),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
     idx_device = [
@@ -207,13 +207,16 @@ def time_executor(
     iters: int = 20,
     warmup: int = 3,
     seed: int = 0,
+    sharding=None,
 ) -> float:
     """Measured wall seconds per exchange of a bound executor.
 
     The one timing protocol shared by ``benchmarks.amg_comm`` and
     ``amg.distributed`` (jit + compile call + warmup + timed loop), so the
     two measured paths cannot drift.  ``dtype`` defaults to float64 to match
-    the plans' ``value_bytes=8`` modeling assumption.
+    the plans' ``value_bytes=8`` modeling assumption.  ``sharding`` places
+    the input block-per-device first, so the timed calls move no input
+    between devices.
     """
     import jax
 
@@ -232,6 +235,8 @@ def time_executor(
             f"requested {np.dtype(dtype)} but device materialized {x.dtype};"
             " enable jax_enable_x64 (or pass the narrower dtype explicitly)"
         )
+    if sharding is not None:
+        x = jax.device_put(x, sharding)
     fn(x).block_until_ready()  # compile
     for _ in range(warmup):
         fn(x).block_until_ready()

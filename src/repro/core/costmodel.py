@@ -22,6 +22,7 @@ from typing import Dict, Sequence, Tuple
 
 import numpy as np
 
+from ..chips import chip
 from .plan import CommPlan, PlanStats, Topology
 
 
@@ -139,8 +140,9 @@ def init_time(plan: CommPlan, params: MachineParams,
 # constants): HBM-bound sparse streams vs VPU multiply-add throughput.
 # ---------------------------------------------------------------------------
 
-#: v5e HBM bandwidth and VPU f32 multiply-add throughput (per chip).
-V5E_HBM_BW = 819e9
+#: v5e HBM bandwidth (``repro.chips``) and modeled VPU f32 multiply-add
+#: throughput (per chip).
+V5E_HBM_BW = chip("TPU v5 lite").hbm_bytes_per_s
 V5E_VPU_FLOPS = 1.97e12 / 4
 
 #: Fixed cost of one extra kernel dispatch (the overlap split adds one).

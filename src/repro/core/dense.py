@@ -615,7 +615,7 @@ def bind_dense(plan: DensePlan, mesh, axis_name: str) -> Callable:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
 
     run = dense_round_runner(plan, axis_name)
     n_seg, cmax = len(plan.counts), plan.cmax
@@ -645,7 +645,7 @@ def bind_dense(plan: DensePlan, mesh, axis_name: str) -> Callable:
     spec = P(axis_name)
     return shard_map(
         per_device, mesh=mesh, in_specs=(spec,), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

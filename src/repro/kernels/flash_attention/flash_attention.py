@@ -19,9 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import pallas_tpu_compiler_params
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -29,6 +28,7 @@ NEG_INF = -1e30
 
 
 def _attn_kernel(
+    lens_ref,                 # SMEM [2] int32: kv_len, q_offset
     q_ref, k_ref, v_ref,      # [1, bq, d], [1, bk, d], [1, bk, d]
     o_ref,                    # [1, bq, d]
     m_scr, l_scr, acc_scr,    # VMEM scratch: [bq, 1], [bq, 1], [bq, d]
@@ -38,12 +38,12 @@ def _attn_kernel(
     window: int,
     block_q: int,
     block_k: int,
-    kv_len: int,
-    q_offset: int,
     num_kv_blocks: int,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    kv_len = lens_ref[0]
+    q_offset = lens_ref[1]
 
     @pl.when(ki == 0)
     def _init():
@@ -101,19 +101,25 @@ def flash_attention_bh(
     scale: float,
     causal: bool,
     window: int = 0,
-    kv_len: int | None = None,
-    q_offset: int = 0,
+    kv_len=None,
+    q_offset=0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Attention over flattened (batch*heads) with pre-padded shapes
-    (ops.py guarantees Tq % block_q == 0, Tk % block_k == 0)."""
+    (ops.py guarantees Tq % block_q == 0, Tk % block_k == 0).
+
+    ``kv_len`` and ``q_offset`` may be traced (a decode step's cache
+    length): they reach the kernel as scalar-prefetch operands, not as
+    constants of its body."""
     BH, Tq, d = q.shape
     Tk = k.shape[1]
     kv_len = Tk if kv_len is None else kv_len
     nq = Tq // block_q
     nk = Tk // block_k
+    lens = jnp.stack([jnp.asarray(kv_len, jnp.int32),
+                      jnp.asarray(q_offset, jnp.int32)])
 
     kernel = functools.partial(
         _attn_kernel,
@@ -122,27 +128,30 @@ def flash_attention_bh(
         window=window,
         block_q=block_q,
         block_k=block_k,
-        kv_len=kv_len,
-        q_offset=q_offset,
         num_kv_blocks=nk,
     )
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(BH, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, j, lens: (b, i, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j, lens: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j, lens: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Tq, d), q.dtype),
+        out_specs=pl.BlockSpec((1, block_q, d),
+                               lambda b, i, j, lens: (b, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((BH, Tq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v)
+    )(lens, q, k, v)

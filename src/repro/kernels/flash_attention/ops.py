@@ -6,7 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .. import backend
+from .. import impl
 from .flash_attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention_bh
 from .ref import attention_ref
 
@@ -35,7 +35,8 @@ def attention(
     block_k: int = DEFAULT_BLOCK_K,
 ) -> jnp.ndarray:
     """GQA attention; dispatches to the Pallas kernel or the jnp oracle."""
-    if backend() == "reference":
+    mode = impl("flash_attention")
+    if mode == "xla":
         return attention_ref(
             q, k, v, scale=scale, causal=causal, window=window,
             kv_len=kv_len, q_offset=q_offset,
@@ -61,6 +62,6 @@ def attention(
         qf, kf, vf,
         scale=scale, causal=causal, window=window, kv_len=kv_len,
         q_offset=q_offset, block_q=bq, block_k=bk,
-        interpret=(backend() == "pallas_interpret"),
+        interpret=(mode == "pallas_interpret"),
     )
     return out.reshape(B, Hq, qp.shape[2], d)[:, :, :Tq]

@@ -22,9 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import pallas_tpu_compiler_params
 
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_D = 512
@@ -58,7 +57,7 @@ def gather_rows(
         ],
         out_specs=pl.BlockSpec((bm, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, D), x.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -101,7 +100,7 @@ def combine_rows(
         ],
         out_specs=pl.BlockSpec((bm, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((T, D), buf.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from .. import backend
+from .. import impl
 from .moe_pack import combine_rows, gather_rows
 from .ref import combine_rows_ref, gather_rows_ref
 
@@ -15,8 +15,8 @@ def _pad_rows(x, mult):
 
 def pack(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """out[i] = x[idx[i]]; idx may contain N-1 pointing at a pad row."""
-    mode = backend()
-    if mode == "reference":
+    mode = impl("moe_pack")
+    if mode == "xla":
         return gather_rows_ref(x, idx)
     M, D = idx.shape[0], x.shape[1]
     bm = 256
@@ -36,8 +36,8 @@ def pack(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
 
 
 def combine(buf: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    mode = backend()
-    if mode == "reference":
+    mode = impl("moe_pack")
+    if mode == "xla":
         return combine_rows_ref(buf, idx, w)
     T, D = idx.shape[0], buf.shape[1]
     bm = 256

@@ -6,7 +6,7 @@ from typing import Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from .. import backend
+from .. import impl
 from .ref import (
     spmv_ell_blocked_partial_ref,
     spmv_ell_blocked_ref,
@@ -42,8 +42,8 @@ def csr_to_ell(
 
 def spmv(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Flat ELL SpMV: whole x VMEM-resident (kernel pads the row count)."""
-    mode = backend()
-    if mode == "reference":
+    mode = impl("spmv_ell")
+    if mode == "xla":
         return spmv_ell_ref(cols, vals, x)
     return spmv_ell(cols, vals, x, interpret=(mode == "pallas_interpret"))
 
@@ -68,8 +68,8 @@ def spmv_blocked(
             f"cols width {cols.shape[1]} not divisible by the "
             f"{x.shape[0] // block_cols} x buckets"
         )
-    mode = backend()
-    if mode == "reference":
+    mode = impl("spmv_ell")
+    if mode == "xla":
         return spmv_ell_blocked_ref(cols, vals, x, block_cols)
     return spmv_ell_blocked(
         cols, vals, x, block_cols=block_cols,
@@ -101,8 +101,8 @@ def spmv_blocked_partial(
             f"cols width {cols.shape[1]} not divisible by n_buckets "
             f"{n_buckets}"
         )
-    mode = backend()
-    if mode == "reference":
+    mode = impl("spmv_ell")
+    if mode == "xla":
         return spmv_ell_blocked_partial_ref(
             cols, vals, x, y0, lo, hi, block_cols, n_buckets
         )
@@ -137,8 +137,8 @@ def spmv_blocked_skip(
             f"cols width {cols.shape[1]} not divisible by n_buckets "
             f"{n_buckets}"
         )
-    mode = backend()
-    if mode == "reference":
+    mode = impl("spmv_ell")
+    if mode == "xla":
         lo = int(bucket_base)
         hi = lo + x.shape[0] // int(block_cols)
         y0r = y0 if y0 is not None else jnp.zeros(cols.shape[0], vals.dtype)

@@ -5,8 +5,15 @@ import jax.numpy as jnp
 
 
 def spmv_ell_ref(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray):
-    """cols/vals: [R, K]; x: [N] -> y [R]."""
-    return jnp.sum(vals * x[cols], axis=1)
+    """cols/vals: [R, K]; x: [N] -> y [R].
+
+    The gather and the sum run over the transposed ``[K, R]`` view: XLA's
+    TPU backend then keeps rows on the minor axis.  Gathering ``[R, K]``
+    directly pads K (7 for the fine AMG level) to the 128-lane tile, which
+    costs 9x the temporary memory at 2^20 rows and 10x the compile time at
+    2^16 rows.
+    """
+    return jnp.sum(vals.T * x[cols.T], axis=0)
 
 
 def spmv_ell_blocked_ref(
@@ -25,7 +32,7 @@ def spmv_ell_blocked_ref(
         jnp.arange(C, dtype=cols.dtype) * jnp.asarray(block_cols, cols.dtype),
         K,
     )
-    return jnp.sum(vals * x[cols + base[None, :]], axis=1)
+    return jnp.sum(vals.T * x[(cols + base[None, :]).T], axis=0)
 
 
 def spmv_ell_blocked_partial_ref(
@@ -46,4 +53,4 @@ def spmv_ell_blocked_partial_ref(
         * jnp.asarray(block_cols, cols.dtype),
         K,
     )
-    return y0 + jnp.sum(sl_vals * x[sl_cols + base[None, :]], axis=1)
+    return y0 + jnp.sum(sl_vals.T * x[(sl_cols + base[None, :]).T], axis=0)

@@ -46,9 +46,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import pallas_tpu_compiler_params
 
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_COLS = 512
@@ -99,7 +98,7 @@ def spmv_ell(
         ],
         out_specs=pl.BlockSpec((br, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, 1), vals.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -158,7 +157,7 @@ def spmv_ell_blocked(
         ],
         out_specs=pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, 1), vals.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -234,7 +233,7 @@ def spmv_ell_blocked_partial(
         ],
         out_specs=pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, 1), vals.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -317,7 +316,7 @@ def spmv_ell_blocked_skip(
         _spmv_blocked_skip_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Rp, 1), vals.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .. import backend
+from .. import impl
 from .ref import ssd_chunked_ref, ssd_ref
 from .ssd_scan import DEFAULT_CHUNK, ssd_scan_h
 
@@ -40,8 +40,8 @@ def ssd(
     Bhh = jnp.moveaxis(Bh, 2, 1)
     Chh = jnp.moveaxis(Ch, 2, 1)
 
-    mode = backend()
-    if mode == "reference":
+    mode = impl("ssd_scan")
+    if mode == "xla":
         fn = lambda xx, dd, bb, cc: ssd_chunked_ref(
             xx, dd, A, bb, cc, chunk=min(chunk, max(8, xx.shape[1]))
         ) if xx.shape[1] % min(chunk, max(8, xx.shape[1])) == 0 else ssd_ref(

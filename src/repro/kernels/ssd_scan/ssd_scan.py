@@ -20,9 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import pallas_tpu_compiler_params
 
 DEFAULT_CHUNK = 128
 
@@ -110,7 +109,7 @@ def ssd_scan_h(
         out_specs=pl.BlockSpec((1, chunk, P), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((H, T, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -32,7 +32,7 @@ RESULTS_DIR = os.path.join(
 
 def cell_path(arch: str, shape: str, mesh: str, moe_mode: str,
               fsdp: bool = False, remat: bool = True,
-              variant: str = "") -> str:
+              variant: str = "", out_dir: str = RESULTS_DIR) -> str:
     tag = f"{arch}__{shape}__{mesh}"
     if moe_mode != "hier":
         tag += f"__{moe_mode}"
@@ -42,7 +42,7 @@ def cell_path(arch: str, shape: str, mesh: str, moe_mode: str,
         tag += "__noremat"
     if variant:
         tag += f"__{variant}"
-    return os.path.join(RESULTS_DIR, tag + ".json")
+    return os.path.join(out_dir, tag + ".json")
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str, moe_mode: str,
@@ -54,7 +54,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, moe_mode: str,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from .. import configs
-    from ..compat import cost_analysis_dict
     from ..configs.shapes import SHAPES, skip_reason
     from ..models import Model, serving
     from ..train import TrainerConfig, jit_train_step, make_train_state
@@ -184,7 +183,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, moe_mode: str,
             sj, _ = jit_train_step(m_x, tcfg)
             st = make_train_state(m_x, tcfg, abstract=True)
             comp = sj.lower(st, batch_sds(S, True)).compile()
-            c = cost_analysis_dict(comp)
+            c = comp.cost_analysis()
             txt = comp.as_text()
             cl = collective_bytes_from_hlo(txt)
             dc = (dci_bytes_from_hlo(txt) if mesh_kind == "multi"
@@ -251,7 +250,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, moe_mode: str,
     t_compile = time.time()
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes_from_hlo(hlo)
     dci = dci_bytes_from_hlo(hlo) if mesh_kind == "multi" else None
@@ -355,6 +354,8 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--timeout", type=int, default=3000)
+    ap.add_argument("--out-dir", default=RESULTS_DIR,
+                    help="directory the cell JSON is written to")
     args = ap.parse_args()
 
     if args.all:
@@ -371,7 +372,7 @@ def main():
         ]
         failures = []
         for a, s, m in todo:
-            path = cell_path(a, s, m, args.moe_mode)
+            path = cell_path(a, s, m, args.moe_mode, out_dir=args.out_dir)
             if os.path.exists(path) and not args.force:
                 try:
                     with open(path) as f:
@@ -383,7 +384,7 @@ def main():
                     continue
             cmd = [sys.executable, "-m", "repro.launch.dryrun",
                    "--arch", a, "--shape", s, "--mesh", m,
-                   "--moe-mode", args.moe_mode]
+                   "--moe-mode", args.moe_mode, "--out-dir", args.out_dir]
             if args.fsdp:
                 cmd.append("--fsdp")
             if args.no_remat:
@@ -413,7 +414,7 @@ def main():
         variant = (variant + "_" if variant else "") + "seqshard"
     path = cell_path(args.arch, args.shape, args.mesh, args.moe_mode,
                      fsdp=args.fsdp, remat=not args.no_remat,
-                     variant=variant)
+                     variant=variant, out_dir=args.out_dir)
     write_cell(result, path)
     print(json.dumps(result, indent=1))
     if result["status"] == "error":

@@ -22,8 +22,11 @@ from __future__ import annotations
 import re
 from typing import Dict, Optional, Tuple
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # B/s / chip
+from ..chips import chip
+
+_V5E = chip("TPU v5 lite")   # the dry-run's target chip
+PEAK_FLOPS = _V5E.peak_flops     # bf16 / chip
+HBM_BW = _V5E.hbm_bytes_per_s    # B/s / chip
 ICI_BW_PER_LINK = 50e9       # B/s
 ICI_LINKS = 2                # effective links engaged per chip (conservative)
 
@@ -201,7 +204,7 @@ def analytic_memory_estimate(cfg, kind: str, B: int, S: int,
         out["transient_bytes"] = 8.0 * b_dev * max(S if kind == "prefill"
                                                    else 1, 1) * d * 4.0
     out["total_bytes"] = float(sum(out.values()))
-    out["fits_16gb_v5e"] = bool(out["total_bytes"] < 16e9)
+    out["fits_16gb_v5e"] = bool(out["total_bytes"] < _V5E.hbm_bytes)
     return out
 
 

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .compile_cache import configure_compile_cache
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -41,6 +43,7 @@ def main():
     ap.add_argument("--shrink-to", type=int, default=0,
                     help="surviving device count (default: half)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.elastic:
         return _main_elastic(args)

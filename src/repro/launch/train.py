@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .compile_cache import configure_compile_cache
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -39,6 +41,7 @@ def main():
                 help="MoE dispatch: auto (Section-5 selection) | a2a | hier | hier_dedup | dense")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
+    configure_compile_cache()
 
     from .. import configs
     from ..models import Model

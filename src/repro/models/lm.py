@@ -35,7 +35,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from .attention import (
     gqa_attention,
@@ -79,9 +79,13 @@ class Model:
         seq_shard: bool = False,
     ):
         self.cfg = cfg
-        from ..compat import make_mesh_auto
-        self.mesh = mesh if mesh is not None else make_mesh_auto(
+        mesh = mesh if mesh is not None else jax.make_mesh(
             (1, 1), ("data", "model")
+        )
+        # the model's sharding constraints name mesh axes, which JAX
+        # accepts only on Auto axes; jax.make_mesh builds Explicit ones
+        self.mesh = mesh.update(
+            axis_types=(AxisType.Auto,) * len(mesh.axis_names)
         )
         self.moe_mode = moe_mode
         self.ep_over_pods = ep_over_pods

@@ -572,7 +572,7 @@ def gather_expert_weights(
     returned :class:`~repro.core.dense.DenseSelection` is the recorded
     choice, the way ``DistOp`` records ``kern=``/``ov=``.
     """
-    from ..compat import shard_map
+    from jax import shard_map
     from ..core import dense_round_runner
 
     if len(plan.ep_axes) != 1:
@@ -621,7 +621,7 @@ def gather_expert_weights(
         per_device, mesh=mesh,
         in_specs=(P(None, axis, None, None),) * len(EXPERT_WEIGHT_KEYS),
         out_specs=(P(),) * len(EXPERT_WEIGHT_KEYS),
-        check_rep=False,
+        check_vma=False,
     )
     gathered = jax.jit(fn)(*(moe_params[k] for k in EXPERT_WEIGHT_KEYS))
     out = dict(moe_params)
@@ -882,7 +882,7 @@ def moe_layer(
     geometry + mode, so an adaptively re-selected plan that lands back on
     a previously compiled mode recompiles nothing.
     """
-    from ..compat import shard_map
+    from jax import shard_map
 
     axes = dict(zip(mesh.axis_names, mesh.devices.shape))
     Pm = axes["model"]

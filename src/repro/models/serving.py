@@ -53,8 +53,10 @@ def _prefill_attn(p_l, x, pos, cfg, window, max_len):
         ck, cv = k[:, :, T - Lc:], v[:, :, T - Lc:]
         filled = Lc
     else:
-        ck = jnp.zeros((B, Hkv, Lc, dh), k.dtype).at[:, :, :T].set(k)
-        cv = jnp.zeros((B, Hkv, Lc, dh), v.dtype).at[:, :, :T].set(v)
+        # a pad keeps k's sharding, where a scatter into fresh zeros has
+        # no output sharding on a mesh with Explicit axes
+        tail = [(0, 0), (0, 0), (0, Lc - T), (0, 0)]
+        ck, cv = jnp.pad(k, tail), jnp.pad(v, tail)
         filled = T
     return out, {"k": ck, "v": cv}
 

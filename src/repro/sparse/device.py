@@ -229,16 +229,35 @@ def partitioned_to_ell_blocked(
 
 
 # --------------------------------------------------------------- selection
-#: Usable VMEM per TPU core; the working budget defaults to half of it
-#: (double buffering + headroom for the rest of the fused program).
-VMEM_BYTES_PER_CORE = 16 * 2 ** 20
+#: VMEM per core that runs off the TPU (tests, CPU examples) model: a
+#: 16 MiB core, small enough that test-sized operators exercise both
+#: layouts.  On a TPU the figure comes from ``repro.chips``.
+MODELED_VMEM_BYTES = 16 * 2 ** 20
 _IDX_BYTES = 4  # int32 column indices
 
 
+def vmem_bytes_per_core() -> int:
+    """VMEM per core of the device this process runs on.
+
+    A TPU's figure is looked up by ``device_kind`` in
+    :data:`repro.chips.CHIPS`; a kind missing there raises.
+    """
+    import jax
+
+    from ..chips import chip
+
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return chip(dev.device_kind).vmem_bytes
+    return MODELED_VMEM_BYTES
+
+
 def default_spmv_vmem_limit() -> int:
-    """Flat-vs-blocked threshold; ``REPRO_SPMV_VMEM_LIMIT_BYTES`` overrides."""
+    """Flat-vs-blocked threshold: half a core's VMEM (double buffering and
+    headroom for the rest of the fused program);
+    ``REPRO_SPMV_VMEM_LIMIT_BYTES`` overrides."""
     env = os.environ.get("REPRO_SPMV_VMEM_LIMIT_BYTES")
-    return int(env) if env else VMEM_BYTES_PER_CORE // 2
+    return int(env) if env else vmem_bytes_per_core() // 2
 
 
 def spmv_flat_vmem_bytes(
@@ -571,7 +590,7 @@ def make_distributed_spmv(
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
     from ..kernels.spmv_ell.ops import spmv
 
     if ell.ghost_pad and exchange is None:
@@ -600,11 +619,11 @@ def make_distributed_spmv(
 
         mm_local = shard_map(
             per_device_local, mesh=mesh, in_specs=(spec,) * 3,
-            out_specs=spec, check_rep=False,
+            out_specs=spec, check_vma=False,
         )
         mm_ghost = shard_map(
             per_device_ghost, mesh=mesh, in_specs=(spec,) * 4,
-            out_specs=spec, check_rep=False,
+            out_specs=spec, check_vma=False,
         )
 
         def spmv_fn(x):
@@ -632,7 +651,7 @@ def make_distributed_spmv(
         mesh=mesh,
         in_specs=(spec,) * 6,
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
     def spmv_fn(x):
@@ -664,7 +683,7 @@ def _make_distributed_spmv_blocked(
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
     from ..kernels.spmv_ell.ops import (
         spmv_blocked,
         spmv_blocked_partial,
@@ -736,12 +755,12 @@ def _make_distributed_spmv_blocked(
         mm_local = shard_map(
             per_device_local, mesh=mesh,
             in_specs=(spec,) * (3 + 2 * local_skip),
-            out_specs=spec, check_rep=False,
+            out_specs=spec, check_vma=False,
         )
         mm_ghost = shard_map(
             per_device_ghost, mesh=mesh,
             in_specs=(spec,) * (4 + 2 * ghost_skip),
-            out_specs=spec, check_rep=False,
+            out_specs=spec, check_vma=False,
         )
 
         def spmv_fn(x):
@@ -777,7 +796,7 @@ def _make_distributed_spmv_blocked(
         mesh=mesh,
         in_specs=(spec,) * (4 + 2 * use_skip),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
     def spmv_fn(x):

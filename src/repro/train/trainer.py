@@ -25,7 +25,7 @@ import numpy as np
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..core import (
     TPU_V5E,
     DenseSelection,
@@ -289,7 +289,7 @@ def make_dp_train_step(
 
     mapped = shard_map(
         per_shard, mesh=mesh, in_specs=(P(), P(axis_name)),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:

@@ -28,10 +28,10 @@ import numpy as np
 from ..kernels.spmv_ell import DEFAULT_BLOCK_COLS, DEFAULT_BLOCK_ROWS
 from ..sparse.device import (
     _IDX_BYTES,
-    VMEM_BYTES_PER_CORE,
     row_block_bucket_map,
     spmv_blocked_vmem_bytes,
     spmv_flat_vmem_bytes,
+    vmem_bytes_per_core,
 )
 from .invariants import VerifyError, _fail
 
@@ -135,10 +135,11 @@ def verify_kernel_budget(
             selection.blocked_bytes < actual:
         _fail("kernel selection under-reports the blocked footprint",
               recorded=selection.blocked_bytes, actual=actual)
+    vmem = vmem_bytes_per_core()
     if selection is not None and selection.variant == variant and \
-            actual > VMEM_BYTES_PER_CORE:
+            actual > vmem:
         _fail("selected kernel's actual footprint exceeds physical VMEM",
-              variant=variant, actual=actual, vmem=VMEM_BYTES_PER_CORE)
+              variant=variant, actual=actual, vmem=vmem)
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,8 @@
 # Tier-1 test runner with a deterministic multidevice environment.
 #
 # shard_map tests (collectives, distributed AMG) need several devices; on
-# CPU-only machines XLA fakes them with --xla_force_host_platform_device_count
-# (set BEFORE any jax import, hence here and not in conftest).  Usage:
+# CPU-only machines XLA fakes them with --xla_force_host_platform_device_count,
+# which tests/conftest.py sets before any jax import.  Usage:
 #
 #   bash test.sh                       # whole tier-1 suite
 #   bash test.sh tests/test_core_plan.py -k rounds
@@ -11,7 +11,6 @@ set -euo pipefail
 cd "$(dirname "$0")"
 
 export PYTHONPATH="$PWD/src${PYTHONPATH:+:$PYTHONPATH}"
-export XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}"
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 # Verify-on-insertion: every plan entering the PlanCache is statically
 # checked (repro.verify) in tests/CI; production hot paths leave it unset.

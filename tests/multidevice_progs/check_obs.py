@@ -48,9 +48,13 @@ def make_engine(observe: bool, adaptive: bool, refit_every: int = 8):
     model = Model(cfg, mesh=mesh, moe_mode="auto", remat=False,
                   moe_cap_factor=8.0)
     params = model.init_params(seed=0)
+    # The drift reference spans the planner's whole 8-observation window:
+    # a decode step routes only 8 (token, expert) pairs over 4 experts, and
+    # a 2-step reference of 16 pairs lets steady routing noise alone cross
+    # the 0.3 threshold before the router is zeroed.
     return ServeEngine(model, params, batch_slots=2, max_len=96,
                        adaptive=adaptive, drift_threshold=0.3,
-                       drift_warmup=2, observe=observe,
+                       drift_warmup=8, observe=observe,
                        refit_every=refit_every), cfg
 
 

@@ -21,18 +21,20 @@ def run_dryrun(*args, timeout=900):
     return out
 
 
-def test_skipped_cell_reports_reason():
+def test_skipped_cell_reports_reason(tmp_path):
     out = run_dryrun("--arch", "nemotron-4-15b", "--shape", "long_500k",
-                     "--mesh", "single", timeout=300)
+                     "--mesh", "single", "--out-dir", str(tmp_path),
+                     timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     d = json.loads(out.stdout)
     assert d["status"] == "skipped"
     assert "sub-quadratic" in d["reason"]
 
 
-def test_train_cell_compiles_and_reports_roofline():
+def test_train_cell_compiles_and_reports_roofline(tmp_path):
     out = run_dryrun("--arch", "qwen1.5-0.5b", "--shape", "train_4k",
-                     "--mesh", "single", "--force")
+                     "--mesh", "single", "--force",
+                     "--out-dir", str(tmp_path))
     assert out.returncode == 0, out.stderr[-3000:]
     d = json.loads(out.stdout)
     assert d["status"] == "ok"
@@ -43,3 +45,7 @@ def test_train_cell_compiles_and_reports_roofline():
     assert d["bottleneck"] in ("compute", "memory", "collective")
     assert 0.05 < d["useful_flops_ratio"] <= 1.5
     assert d["memory_analytic"]["fits_16gb_v5e"] is True
+    # the cell lands where it was told, never over the committed results
+    assert json.loads(
+        (tmp_path / "qwen1.5-0.5b__train_4k__single.json").read_text()
+    ) == d

@@ -74,6 +74,26 @@ def test_kv_len_padding_mask():
                                rtol=2e-5, atol=2e-5)
 
 
+def test_kv_len_traced_decode():
+    """A decode step's cache length is traced: the kernel takes it (and
+    the query offset) as operands, as serving's decode passes them."""
+    rng = np.random.default_rng(3)
+    B, H, T, d = 1, 2, 64, 32
+    q = rand(rng, (B, H, 1, d), jnp.float32)
+    k = rand(rng, (B, H, T, d), jnp.float32)
+    v = rand(rng, (B, H, T, d), jnp.float32)
+    kv_len = 37
+    want = attention_ref(q, k, v, causal=True, kv_len=kv_len,
+                         q_offset=kv_len - 1)
+    with use_backend("pallas_interpret"):
+        got = jax.jit(
+            lambda n: attention(q, k, v, causal=True, kv_len=n,
+                                q_offset=n - 1, block_q=8, block_k=64)
+        )(jnp.int32(kv_len))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("Tq,Tk,chunk_gt", [(64, 300, True), (1, 4000, True)])
 def test_chunked_ref_matches_naive(Tq, Tk, chunk_gt):
     """The chunked (scan) reference == naive reference on long KV."""

@@ -177,7 +177,7 @@ def test_dropped_fraction_excludes_padding_rows():
     hot expert, dropped is 1 - 8/12, not 1 - 8/16."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.models.moe import moe_dispatch_lane
 
     cfg = moe_cfg(n_experts=1, top_k=1)
