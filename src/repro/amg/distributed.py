@@ -470,7 +470,8 @@ class DistributedHierarchy:
             zero = jnp.zeros((), rank.dtype)
             buf = jnp.zeros((P_, pad), b_blk.dtype)
             buf = jax.lax.dynamic_update_slice(buf, b_blk, (rank, zero))
-            full = run(buf).reshape(-1)     # replicated coarse rhs
+            with jax.named_scope("exchange"):
+                full = run(buf).reshape(-1)  # replicated coarse rhs
             x = coarse_cheby(full).reshape(P_, pad)
             return jax.lax.dynamic_slice(x, (rank, zero), (1, pad))
 
@@ -533,15 +534,17 @@ class DistributedHierarchy:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as PSpec
 
-        self._step, consts = self.step_program()
-        replicated = NamedSharding(self.mesh, PSpec())
-        # block-sharded operands are already placed; host constants traced
-        # into the step (scalars, the dense coarse operator) are replicated
-        self._consts = [
-            c if isinstance(c, jax.Array) and c.committed
-            else jax.device_put(c, replicated)
-            for c in consts
-        ]
+        with _OBS.span("amg/step_program", levels=len(self.levels)):
+            self._step, consts = self.step_program()
+            replicated = NamedSharding(self.mesh, PSpec())
+            # block-sharded operands are already placed; host constants
+            # traced into the step (scalars, the dense coarse operator) are
+            # replicated
+            self._consts = [
+                c if isinstance(c, jax.Array) and c.committed
+                else jax.device_put(c, replicated)
+                for c in consts
+            ]
         return self._step
 
     def _cheby(self, k: int, x, b, degree: int):
@@ -568,28 +571,47 @@ class DistributedHierarchy:
         return x
 
     def _vcycle(self, k: int, b):
+        """One V-cycle from level ``k`` down.  Each level's work sits under
+        the named scope ``L<k>`` and one of its phases (``pre``,
+        ``residual``, ``restrict``, ``prolong``, ``post``, ``coarse``); the
+        recursion into level k+1 stays outside it, so every operation of
+        the compiled step names exactly one level."""
+        import jax
         import jax.numpy as jnp
 
         lv = self.levels[k]
-        zero = jnp.zeros_like(b)
+        level = f"L{k}"
         if lv.R is None or k == len(self.levels) - 1:
-            if self._coarse_fn is not None:
-                return self._coarse_fn(b)
-            return self._cheby(k, zero, b, degree=24)
-        x = self._cheby(k, zero, b, degree=3)       # pre-smooth
-        r = b - self._Amv[k](x)
-        rc = self._Rmv[k](r)
+            with jax.named_scope(level), jax.named_scope("coarse"):
+                if self._coarse_fn is not None:
+                    return self._coarse_fn(b)
+                return self._cheby(k, jnp.zeros_like(b), b, degree=24)
+        with jax.named_scope(level):
+            with jax.named_scope("pre"):
+                x = self._cheby(k, jnp.zeros_like(b), b, degree=3)
+            with jax.named_scope("residual"):
+                r = b - self._Amv[k](x)
+            with jax.named_scope("restrict"):
+                rc = self._Rmv[k](r)
         ec = self._vcycle(k + 1, rc)
-        x = x + self._Pmv[k](ec)
-        return self._cheby(k, x, b, degree=3)       # post-smooth
+        with jax.named_scope(level):
+            with jax.named_scope("prolong"):
+                x = x + self._Pmv[k](ec)
+            with jax.named_scope("post"):
+                return self._cheby(k, x, b, degree=3)
 
     def _make_step(self):
+        import jax
         import jax.numpy as jnp
 
         def step(x, b):
-            r = b - self._Amv[0](x)
-            rn = jnp.linalg.norm(r)
-            return x + self._vcycle(0, r), rn
+            # ``outer``: the step's own residual, its norm and the update
+            with jax.named_scope("outer"):
+                r = b - self._Amv[0](x)
+                rn = jnp.linalg.norm(r)
+            v = self._vcycle(0, r)
+            with jax.named_scope("outer"):
+                return x + v, rn
 
         return step
 
@@ -624,26 +646,31 @@ class DistributedHierarchy:
                 blocks,
             )
 
-        bg = place(b)
-        x = place(np.zeros(len(b)) if x0 is None else x0)
-        nb = max(float(np.linalg.norm(b)), 1e-300)
-        step = self._device_step()
         hist: List[float] = []
         with _OBS.span("amg/solve", n=lv0.n, tol=tol,
                        max_iters=max_iters) as sp:
+            with _OBS.span("amg/place"):
+                bg = place(b)
+                x = place(np.zeros(len(b)) if x0 is None else x0)
+            nb = max(float(np.linalg.norm(b)), 1e-300)
+            step = self._device_step()
             for it in range(max_iters):
-                # the float() is the device sync: the iteration span
-                # covers the whole V-cycle, not just its dispatch
+                # the iteration span covers the whole V-cycle: its
+                # dispatch, then the float() that waits for the device
                 with _OBS.span("amg/vcycle_iter", iter=it):
-                    x_new, rn = step(self._consts, x, bg)
-                    rel = float(rn) / nb
+                    with _OBS.span("amg/dispatch"):
+                        x_new, rn = step(self._consts, x, bg)
+                    with _OBS.span("amg/sync"):
+                        rel = float(rn) / nb
                 hist.append(rel)
                 if rel < tol:
                     break
                 x = x_new
             sp.set(iters=len(hist), final_rel=hist[-1] if hist else 0.0)
-        self.x_device = x
-        return unpack_vector(lv0.A.part.offsets, np.asarray(x)), hist
+            self.x_device = x
+            with _OBS.span("amg/unpack"):
+                x_host = unpack_vector(lv0.A.part.offsets, np.asarray(x))
+        return x_host, hist
 
     # ------------------------------------------------------------ elastic
     def _global_hierarchy(self) -> Hierarchy:
@@ -821,48 +848,6 @@ class DistributedHierarchy:
                     sp.set(plan=lv.A.coll.plan, pure_exchange=True,
                            seconds=secs)
             out.append((lv.index, lv.A.strategy, secs))
-        return out
-
-    def measure_spmv_seconds(
-        self, iters: int = 10, warmup: int = 2, tracer=None
-    ) -> List[Tuple[int, str, str, float]]:
-        """Measured per-level wall time of the full jitted distributed SpMV
-        (exchange + kernel, under whatever overlap schedule each level
-        selected); returns [(level, kernel variant, overlap mode, seconds)].
-
-        When ``tracer`` is given, levels with an exchange are recorded
-        against their plan with ``pure_exchange=False``: these timings
-        include kernel compute (like the MoE dispatch rows), so
-        ``merged_rate_samples(pure_only=True)`` must keep them out of the
-        exchange-rate calibration fit.
-        """
-        import jax
-        import jax.numpy as jnp
-
-        rng = np.random.default_rng(0)
-        out = []
-        for k, lv in enumerate(self.levels):
-            fn = jax.jit(self._Amv[k])
-            x = jnp.asarray(
-                rng.normal(
-                    size=(self.topo.n_procs, lv.A.ell.in_pad)
-                ).astype(self.dtype)
-            )
-            for _ in range(warmup + 1):
-                fn(x).block_until_ready()
-            t0 = _now()
-            for _ in range(iters):
-                y = fn(x)
-            y.block_until_ready()
-            secs = (_now() - t0) / iters
-            if tracer is not None and lv.A.ell.ghost_pad:
-                tracer.record_plan(
-                    lv.A.coll.plan, secs,
-                    label=f"amg/L{lv.index}/spmv", pure_exchange=False,
-                )
-            out.append(
-                (lv.index, lv.A.kernel_variant, lv.A.overlap_mode, secs)
-            )
         return out
 
     def _bind_exchange_only(self, op: DistOp) -> Callable:

@@ -17,8 +17,11 @@ from typing import List, Optional
 
 import numpy as np
 
+from ..obs import default_obs
 from ..sparse.csr import CSR
 from .coarsen import direct_interpolation, pmis, strength_graph
+
+_OBS = default_obs()
 
 
 @dataclass
@@ -91,27 +94,36 @@ def build_hierarchy(
     strength_theta: float = 0.25,
     seed: int = 0,
 ) -> Hierarchy:
-    levels = [Level(A=A)]
-    while (
-        levels[-1].A.nrows > min_coarse and len(levels) < max_levels
-    ):
-        Ak = levels[-1].A
-        S = strength_graph(Ak, strength_theta)
-        if S.nnz == 0:
-            break
-        splitting = pmis(S, seed=seed + len(levels))
-        P, splitting = direct_interpolation(Ak, S, splitting)
-        if P.ncols >= Ak.nrows or P.ncols == 0:
-            break
-        levels[-1].splitting = splitting
-        R = P.transpose()
-        AP = Ak.matmat(P)
-        Ac = R.matmat(AP).prune(1e-14)
-        levels[-1].P = P
-        levels[-1].R = R
-        levels.append(Level(A=Ac))
-    for lvl in levels:
-        lvl.rho = estimate_rho(lvl.A)
+    with _OBS.span("amg/build_hierarchy", n=A.nrows) as sp:
+        levels = [Level(A=A)]
+        while (
+            levels[-1].A.nrows > min_coarse and len(levels) < max_levels
+        ):
+            Ak = levels[-1].A
+            with _OBS.span("amg/coarsen_level", level=len(levels) - 1,
+                           n=Ak.nrows):
+                with _OBS.span("amg/strength"):
+                    S = strength_graph(Ak, strength_theta)
+                if S.nnz == 0:
+                    break
+                with _OBS.span("amg/pmis"):
+                    splitting = pmis(S, seed=seed + len(levels))
+                with _OBS.span("amg/interp"):
+                    P, splitting = direct_interpolation(Ak, S, splitting)
+                if P.ncols >= Ak.nrows or P.ncols == 0:
+                    break
+                levels[-1].splitting = splitting
+                with _OBS.span("amg/galerkin"):
+                    R = P.transpose()
+                    AP = Ak.matmat(P)
+                    Ac = R.matmat(AP).prune(1e-14)
+                levels[-1].P = P
+                levels[-1].R = R
+                levels.append(Level(A=Ac))
+        with _OBS.span("amg/estimate_rho"):
+            for lvl in levels:
+                lvl.rho = estimate_rho(lvl.A)
+        sp.set(levels=len(levels))
     return Hierarchy(levels)
 
 

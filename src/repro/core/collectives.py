@@ -137,7 +137,9 @@ def make_executor(
     returns [n_procs, ghost_pad, d] with the delivered values.  The function
     body runs under shard_map; jit it (optionally fusing surrounding compute
     — that is how the paper's start/wait overlap materializes: XLA schedules
-    the ``l`` rounds concurrently with the ``s``/``g`` chain).
+    the ``l`` rounds concurrently with the ``s``/``g`` chain).  Each plan
+    step's operations sit under the named scope ``step_<name>``, so a
+    profile shows the locality-aware phases apart.
     """
     # Device-plan index arrays become sharded constants.
     steps = dplan.steps
@@ -150,19 +152,20 @@ def make_executor(
         buf = None
         for st in steps:
             src = x if st.reads_local else buf
-            out = ghost if st.writes_ghost else jnp.zeros(
-                (st.out_pad + 1,) + x.shape[1:], x.dtype
-            )
-            lg = next(it)[0]
-            ls = next(it)[0]
-            if lg.shape[0] > 0:
-                out = out.at[ls].set(src[lg])
-            for rnd in st.rounds:
-                g = next(it)[0]
-                s = next(it)[0]
-                sendbuf = src[g]
-                recvbuf = jax.lax.ppermute(sendbuf, axis_name, rnd.perm)
-                out = out.at[s].set(recvbuf)
+            with jax.named_scope(f"step_{st.name}"):
+                out = ghost if st.writes_ghost else jnp.zeros(
+                    (st.out_pad + 1,) + x.shape[1:], x.dtype
+                )
+                lg = next(it)[0]
+                ls = next(it)[0]
+                if lg.shape[0] > 0:
+                    out = out.at[ls].set(src[lg])
+                for rnd in st.rounds:
+                    g = next(it)[0]
+                    s = next(it)[0]
+                    sendbuf = src[g]
+                    recvbuf = jax.lax.ppermute(sendbuf, axis_name, rnd.perm)
+                    out = out.at[s].set(recvbuf)
             if st.writes_ghost:
                 ghost = out
             else:

@@ -19,6 +19,16 @@ Usage::
     print(obs.report())                  # rollup table
     obs.export_perfetto("trace.json")    # load in ui.perfetto.dev
 
+**What enabling adds.**  Every span is also a JAX profiler annotation
+(see :mod:`repro.obs.spans`), so a ``jax.profiler`` trace shows the
+program's spans beside the device's operations.  Two counters, labelled
+by JAX's compile event and function name, say which function was traced,
+lowered or compiled, and for how long: ``jax/compiles`` and
+``jax/compile_seconds`` (``backend_compile`` includes a load from the
+persistent compile cache).  Python's garbage collections become
+``py/gc`` spans with their generation and the objects they collected, so
+a host stall that a collection caused is named in the trace.
+
 **TraceRecorder bridge** (the online-calibration pipe): attach a
 ``repro.profile.TraceRecorder`` via ``enable(tracer=...)`` and every
 closing span whose attributes carry ``plan=<CommPlan>`` and
@@ -33,6 +43,8 @@ of ``tools/lint_repro.py`` keeps ad-hoc ``perf_counter`` calls out of
 """
 from __future__ import annotations
 
+import gc
+import weakref
 from typing import Dict, List, Optional
 
 from .export import report as _report
@@ -59,6 +71,13 @@ __all__ = [
     "DEFAULT_TIME_BUCKETS", "DEFAULT_RING_SIZE",
 ]
 
+#: JAX's compile-time monitoring events, by the label they are counted under
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+
 
 class Obs:
     """Metrics registry + span ring + optional TraceRecorder bridge."""
@@ -69,6 +88,7 @@ class Obs:
         self.spans = SpanRecorder(ring_size=ring_size)
         self.spans.on_close = self._on_span_close
         self._tracer = None     # Optional[repro.profile.TraceRecorder]
+        self._gc_span = None    # the open py/gc span, between its callbacks
 
     # ------------------------------------------------------------ state
     @property
@@ -87,6 +107,7 @@ class Obs:
             self.spans.on_close = self._on_span_close
         if tracer is not None:
             self._tracer = tracer
+        _hook(self)
         self._enabled_ref[0] = True
         return self
 
@@ -126,6 +147,27 @@ class Obs:
     def histogram(self, name: str, help: str = "", **kw) -> Histogram:
         return self.metrics.histogram(name, help, **kw)
 
+    # ------------------------------------------------ JAX and gc hooks
+    def _on_jax_event(self, event: str, duration: float, **kw) -> None:
+        label = COMPILE_EVENTS.get(event)
+        if label is None or not self._enabled_ref[0]:
+            return
+        fun = kw.get("fun_name", "")
+        self.metrics.counter("jax/compiles").inc(event=label, fun=fun)
+        self.metrics.counter("jax/compile_seconds").inc(
+            duration, event=label, fun=fun)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if self._enabled_ref[0]:
+                self._gc_span = self.spans.span(
+                    "py/gc", generation=info["generation"])
+                self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            sp, self._gc_span = self._gc_span, None
+            sp.set(collected=info["collected"])
+            sp.__exit__(None, None, None)
+
     # --------------------------------------------------------- bridge
     def _on_span_close(self, ev: SpanEvent) -> None:
         # pure-exchange spans feed the calibration trace: same samples
@@ -140,11 +182,12 @@ class Obs:
                     pure_exchange=True,
                     fingerprint=ev.attrs.get("fingerprint"),
                 )
-        # top-level span close = natural counter-track sample point
-        if ev.depth == 0:
+        # top-level span close = natural counter-track sample point (a
+        # collection is no program boundary, and comes too often)
+        if ev.depth == 0 and ev.name != "py/gc":
             for name, c in sorted(self.metrics._counters.items()):
                 if c._series:
-                    self.spans.counter_sample(name, sum(c._series.values()))
+                    self.spans.counter_sample(name, c.total())
 
     # --------------------------------------------------------- export
     def snapshot(self) -> Dict:
@@ -164,6 +207,31 @@ class Obs:
 
     def export_perfetto(self, path, process_name: str = "repro") -> None:
         save_perfetto(self.spans.events(), path, process_name=process_name)
+
+
+# Every instance ever enabled hears JAX's compile events and Python's
+# collections through one listener and one callback, registered once; an
+# instance no longer referenced drops out of the set.
+_HOOKED: "weakref.WeakSet[Obs]" = weakref.WeakSet()
+
+
+def _hook(obs: Obs) -> None:
+    if _on_gc not in gc.callbacks:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+        gc.callbacks.append(_on_gc)
+    _HOOKED.add(obs)
+
+
+def _on_jax_event(event: str, duration: float, **kw) -> None:
+    for obs in list(_HOOKED):
+        obs._on_jax_event(event, duration, **kw)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    for obs in list(_HOOKED):
+        obs._on_gc(phase, info)
 
 
 _DEFAULT: Obs = Obs()

@@ -60,6 +60,10 @@ class Counter:
     def value(self, **labels) -> float:
         return self._series.get(_label_key(labels), 0.0)
 
+    def total(self) -> float:
+        """The sum over every label series."""
+        return sum(self._series.values())
+
     def clear(self) -> None:
         self._series.clear()
 

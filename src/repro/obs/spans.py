@@ -19,10 +19,17 @@ interleave them on the same clock:
 * ``counter`` — a metric sample for Perfetto counter tracks, emitted by
   ``Obs`` when a top-level span closes.
 
+An open span is also a ``jax.profiler.TraceAnnotation`` of its name (the
+name only: attributes stay in the ring), so while the JAX profiler traces,
+every span lands on the host plane of its ``.xplane.pb`` on the same clock
+as the device's operations.  Outside a trace the annotation costs one
+check.  ``SpanRecorder.totals`` keeps each span name's count and seconds,
+which the ring's evictions do not lose.
+
 The **disabled fast path** returns the module singleton :data:`NULL_SPAN`
-— no ``Span`` object, no ring append, no clock read.  Tests assert the
-identity (``obs.span(...) is NULL_SPAN``) so the fast path cannot
-silently regress into an allocating one.
+— no ``Span`` object, no ring append, no annotation, no clock read.  Tests
+assert the identity (``obs.span(...) is NULL_SPAN``) so the fast path
+cannot silently regress into an allocating one.
 
 The clock is ``time.perf_counter`` re-exported as :func:`now` — the one
 blessed timing call site outside ``repro.profile`` (see
@@ -34,11 +41,22 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Deque, Dict, Optional
+from typing import Any, Deque, Dict, List, Optional
 
 now = time.perf_counter
 
 DEFAULT_RING_SIZE = 65536
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``; JAX is imported on the first
+    span, not with this module."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 @dataclass
@@ -79,7 +97,7 @@ NULL_SPAN = _NullSpan()
 class Span:
     """An open span; close it via the ``with`` protocol."""
 
-    __slots__ = ("name", "attrs", "t0", "_rec", "_depth", "_closed")
+    __slots__ = ("name", "attrs", "t0", "_rec", "_depth", "_closed", "_ann")
 
     def __init__(self, recorder: "SpanRecorder", name: str,
                  attrs: Dict[str, Any]):
@@ -88,6 +106,7 @@ class Span:
         self._rec = recorder
         self._depth = 0
         self._closed = False
+        self._ann = None
         self.t0 = 0.0
 
     def set(self, **attrs) -> "Span":
@@ -98,6 +117,8 @@ class Span:
         stack = self._rec._stack()
         self._depth = len(stack)
         stack.append(self)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self.t0 = now()
         return self
 
@@ -106,6 +127,7 @@ class Span:
         if self._closed:    # defensive: double-exit records once
             return False
         self._closed = True
+        self._ann.__exit__(exc_type, exc, tb)
         stack = self._rec._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -132,6 +154,8 @@ class SpanRecorder:
         self._local = threading.local()
         self.on_close = None        # Optional[Callable[[SpanEvent], None]]
         self.dropped = 0            # ring evictions (ring full)
+        # span name -> [closed spans, seconds], kept whatever the ring drops
+        self.totals: Dict[str, List[float]] = {}
 
     def _stack(self) -> list:
         st = getattr(self._local, "stack", None)
@@ -169,6 +193,9 @@ class SpanRecorder:
                        depth=span._depth, tid=threading.get_ident(),
                        kind="span", attrs=span.attrs)
         self._append(ev)
+        tot = self.totals.setdefault(span.name, [0, 0.0])
+        tot[0] += 1
+        tot[1] += t1 - span.t0
         if self.on_close is not None:
             self.on_close(ev)
 
@@ -181,6 +208,7 @@ class SpanRecorder:
     def clear(self) -> None:
         self.ring.clear()
         self.dropped = 0
+        self.totals.clear()
 
     def tree(self) -> str:
         """Indented close-order listing of spans — the quick-look view

@@ -580,13 +580,19 @@ def make_distributed_spmv(
     behind the local compute.  Both phases accumulate buckets in the same
     ascending order as the fused schedule.  No-ghost operators ignore the
     flag (there is nothing to overlap).
-    """
-    if isinstance(ell, DeviceEllBlocked):
-        return _make_distributed_spmv_blocked(
-            ell, mesh, axis_name, exchange, overlap
-        )
 
+    The product's operations sit under the named scope ``spmv``, the halo
+    exchange's under ``spmv/exchange``, so a profile attributes them.
+    """
     import jax
+
+    if exchange is not None:
+        exchange = jax.named_scope("exchange")(exchange)
+    if isinstance(ell, DeviceEllBlocked):
+        return jax.named_scope("spmv")(_make_distributed_spmv_blocked(
+            ell, mesh, axis_name, exchange, overlap
+        ))
+
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -631,7 +637,7 @@ def make_distributed_spmv(
             y = mm_local(x, *consts[:2])          # no data dep on gh
             return mm_ghost(y, gh, *consts[2:])
 
-        return spmv_fn
+        return jax.named_scope("spmv")(spmv_fn)
 
     def per_device(x_blk, gh_blk, lc, lv, gc, gv):
         # blocks arrive with a leading device dim of 1
@@ -661,7 +667,7 @@ def make_distributed_spmv(
             gh = jnp.zeros((ell.n_procs, 0), x.dtype)
         return mm(x, gh, *consts)
 
-    return spmv_fn
+    return jax.named_scope("spmv")(spmv_fn)
 
 
 def _make_distributed_spmv_blocked(

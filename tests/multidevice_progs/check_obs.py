@@ -16,7 +16,8 @@ Three contracts (the PR-9 acceptance criteria):
    production-step pure-exchange samples through the span bridge.
 3. **AMG span tree** — hierarchy setup + solve emits the expected nested
    span structure (``amg/setup`` > ``amg/build_level`` per level,
-   ``amg/solve`` > ``amg/vcycle_iter`` per iteration), and
+   ``amg/solve`` > ``amg/place``, then ``amg/vcycle_iter`` per iteration
+   split into ``amg/dispatch`` and ``amg/sync``, then ``amg/unpack``), and
    ``measure_exchange_seconds`` bridges one pure sample per level into
    the attached tracer without an explicit tracer argument.
 """
@@ -204,8 +205,18 @@ def check_amg_span_tree():
         assert {"level", "strategy", "kernel", "overlap"} <= set(e.attrs)
     (solve,) = by_name["amg/solve"]
     assert solve.depth == 0 and solve.attrs["iters"] == len(hist)
-    assert len(by_name["amg/vcycle_iter"]) == len(hist) == 5
-    assert all(e.depth == 1 for e in by_name["amg/vcycle_iter"])
+    iters = by_name["amg/vcycle_iter"]
+    assert len(iters) == len(hist) == 5
+    assert all(e.depth == 1 for e in iters)
+    # each iteration is the step's dispatch, then the sync that waits on it
+    for it, d, s in zip(iters, by_name["amg/dispatch"], by_name["amg/sync"]):
+        assert d.depth == s.depth == 2
+        assert it.t0 <= d.t0 <= d.t1 <= s.t0 <= s.t1 <= it.t1
+    assert len(by_name["amg/dispatch"]) == len(by_name["amg/sync"]) == 5
+    (place,) = by_name["amg/place"]
+    (unpack,) = by_name["amg/unpack"]
+    assert place.depth == unpack.depth == 1
+    assert place.t1 <= iters[0].t0 and iters[-1].t1 <= unpack.t0
 
     # no explicit tracer argument: the span bridge carries the samples
     # (one per level that actually exchanges — ghost-free levels skip)

@@ -14,9 +14,7 @@ Checks, on the 48x48 rotated anisotropic diffusion problem:
   3. the default auto selection (off at this scale: local compute is below
      the split overhead) solves correctly and records its per-level
      decision on each operator;
-  4. the decision is visible in kernel_table() and describe() (ov= column);
-  5. measure_spmv_seconds records full-SpMV timings to a TraceRecorder
-     with pure_exchange=False, so they never enter wire-rate calibration.
+  4. the decision is visible in kernel_table() and describe() (ov= column).
 """
 import os
 
@@ -30,7 +28,6 @@ import numpy as np
 
 from repro.amg import DistributedHierarchy, build_hierarchy, diffusion_2d, solve
 from repro.core import PlanCache, Topology
-from repro.profile import TraceRecorder
 from repro.sparse import distributed_spmv, partition_csr
 
 
@@ -102,19 +99,6 @@ def main():
     desc = dh.describe()
     assert "ov=off" in desc
     print(desc)
-
-    # (5) measured SpMV rows are non-pure trace samples
-    tracer = TraceRecorder()
-    rows = dh.measure_spmv_seconds(iters=2, warmup=1, tracer=tracer)
-    assert rows and all(secs > 0 for _, _, _, secs in rows)
-    ghosted_levels = {lv.index for lv in dh.levels
-                      if lv.A.ell.ghost_pad > 0}
-    assert {s.label for s in tracer.samples} \
-        == {f"amg/L{i}/spmv" for i in ghosted_levels}
-    assert all(not s.pure_exchange for s in tracer.samples)
-    assert not tracer.merged_rate_samples()  # excluded from rate fitting
-    print(f"measure_spmv_seconds OK ({len(rows)} levels, "
-          f"{len(tracer.samples)} non-pure samples)")
 
     print("ALL_OK")
 
