@@ -16,7 +16,8 @@ observed optimum.  All plans and bound executors go through a
 
 Solve: a jitted V-cycle (Chebyshev smoother, degrees matching the host
 solver exactly) over ``[P, pad]`` block vectors; matvecs compose the plan
-executor with the padded-ELL SpMV kernel (``sparse.device``).  With the
+executor with the padded-ELL SpMV kernel, or with shifted slices where
+a level's local block is banded (``sparse.device``).  With the
 same rho estimates the device residual history tracks the host
 :func:`~repro.amg.hierarchy.solve` to rounding error.
 
@@ -78,9 +79,10 @@ _OBS = default_obs()
 class DistOp:
     """One partitioned operator + its persistent collective + device form.
 
-    ``kernel`` records the flat-vs-blocked SpMV choice and ``overlap`` the
+    ``kernel`` records the flat-vs-blocked SpMV choice (and the flat
+    layout's diagonal-or-ELL local block) and ``overlap`` the
     exchange/compute-overlap schedule choice, next to the plan's Section-5
-    transport choice, so all three selections travel with the operator.
+    transport choice, so all the selections travel with the operator.
     """
 
     part: PartitionedCSR
@@ -100,6 +102,10 @@ class DistOp:
     @property
     def kernel_variant(self) -> str:
         return self.kernel.variant if self.kernel else "flat"
+
+    @property
+    def local_layout(self) -> str:
+        return self.kernel.local_layout if self.kernel else "ell"
 
     @property
     def overlap_mode(self) -> str:
@@ -207,8 +213,10 @@ class DistributedHierarchy:
         ``spmv_variant="auto"`` likewise selects the flat or column-blocked
         SpMV kernel per operator from its modeled VMEM footprint against
         ``spmv_vmem_limit`` (default: :func:`~repro.sparse.device.
-        default_spmv_vmem_limit`, env-overridable); ``"flat"``/``"blocked"``
-        pin it.  ``spmv_overlap="auto"`` selects the split
+        default_spmv_vmem_limit`, env-overridable), and stores a flat
+        operator's local block by diagonals where its offsets show it banded
+        (a stencil fine level); ``"flat"``/``"blocked"`` pin the variant
+        with the ELL gather.  ``spmv_overlap="auto"`` selects the split
         exchange/compute-overlap schedule per operator whenever the modeled
         hidden exchange time beats the split overhead; ``"on"``/``"off"``
         pin it.  All choices are recorded on each :class:`DistOp`.
@@ -278,6 +286,7 @@ class DistributedHierarchy:
                     levels.append(dl)
                     lsp.set(strategy=A_op.strategy,
                             kernel=A_op.kernel_variant,
+                            layout=A_op.local_layout,
                             overlap=A_op.overlap_mode)
             dh = cls(levels, mesh, axis_name, topo, cache, dtype,
                      strategy, params, value_bytes,
@@ -377,6 +386,7 @@ class DistributedHierarchy:
                     levels.append(dl)
                     lsp.set(strategy=A_op.strategy,
                             kernel=A_op.kernel_variant,
+                            layout=A_op.local_layout,
                             overlap=A_op.overlap_mode)
             dh = cls(levels, mesh, axis_name, topo, cache, dtype,
                      strategy, params, value_bytes,
@@ -767,7 +777,8 @@ class DistributedHierarchy:
         """[(level, op, kernel variant, overlap mode, selection report)] —
         the flat-vs-blocked SpMV choice and the exchange/compute-overlap
         choice per operator, mirroring :meth:`selection_table` for the
-        transport choice."""
+        transport choice; the report names the local layout
+        (``local=ell`` or ``local=diagonal(D=...)``)."""
         rows = []
         for lv in self.levels:
             for name, op in (("A", lv.A), ("R", lv.R), ("P", lv.P)):
@@ -791,6 +802,7 @@ class DistributedHierarchy:
             lines.append(
                 f"  L{lv.index}: n={lv.n:>8,d} pad={lv.pad:>6d} "
                 f"A={lv.A.strategy:8s} kern={lv.A.kernel_variant:7s} "
+                f"local={lv.A.local_layout:8s} "
                 f"ov={lv.A.overlap_mode:4s} "
                 f"inter_msgs={t['inter_msgs']:5d} "
                 f"inter_bytes={t['inter_bytes']:8d}"

@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for ELL SpMV (flat and column-blocked layouts)."""
+"""Pure-jnp bodies for ELL SpMV (flat and column-blocked layouts) and the
+diagonal SpMV of a banded block."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -14,6 +15,30 @@ def spmv_ell_ref(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray):
     2^16 rows.
     """
     return jnp.sum(vals.T * x[cols.T], axis=0)
+
+
+def spmv_dia(offsets: tuple, vals: jnp.ndarray, x: jnp.ndarray):
+    """Banded block stored by diagonals: ``vals [D, R]``, ``x [N]`` -> y [R]
+    with ``y[i] = sum_d vals[d, i] * x[i + offsets[d]]``.
+
+    ``offsets`` is a static ascending tuple of column-minus-row offsets;
+    entries whose column falls outside ``x`` hold 0.0.  With ``m`` the
+    largest offset magnitude, x is zero-padded by ``m`` on each side and
+    each diagonal multiplies one static shifted slice of it, summed in
+    ascending-offset (CSR column) order: no gather, so XLA fuses the
+    slices, products and adds into one streaming loop.
+    """
+    R = vals.shape[1]
+    m = max(abs(o) for o in offsets)
+    xp = jnp.concatenate([
+        jnp.zeros((m,), x.dtype), x,
+        jnp.zeros((m + max(R - x.shape[0], 0),), x.dtype),
+    ])
+    y = vals[0] * xp[m + offsets[0]: m + offsets[0] + R]
+    for d in range(1, len(offsets)):
+        o = m + offsets[d]
+        y = y + vals[d] * xp[o: o + R]
+    return y
 
 
 def spmv_ell_blocked_ref(

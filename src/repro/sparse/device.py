@@ -15,6 +15,16 @@ Two device layouts, selected per operator by VMEM footprint
   zero.  The whole per-device x (local + ghost) is VMEM-resident in the
   kernel — right for coarse levels and small blocks.
 
+  Its local block has two forms, chosen per operator from the block's own
+  structure.  **ell**: the padded-ELL gather above.  **diagonal**: a square
+  partition whose local blocks, taken together, hold ``D`` distinct
+  column-minus-row offsets, with ``D * value_bytes <= K * (value_bytes +
+  4)`` (no more bytes than the ELL it replaces), is stored as ``vals [P, D,
+  row_pad]`` over the static ``offsets`` and applied as ``D`` shifted dense
+  slices of a zero-padded x (``kernels.spmv_ell.spmv_dia``): no gather.
+  Stencil fine levels pass; Galerkin coarse levels, R and P fail by orders
+  of magnitude and keep the gather.  The ghost block stays ELL.
+
 * **column-blocked** (:class:`DeviceEllBlocked`): each row's nonzeros are
   reordered into column buckets of ``block_cols`` x entries; local columns
   fill the leading buckets, ghost columns the *trailing* buckets, so the
@@ -33,7 +43,9 @@ Entry points:
 * :func:`partitioned_to_ell` / :func:`partitioned_to_ell_blocked` —
   ``PartitionedCSR ->`` device form conversions;
 * :func:`select_spmv_kernel` — modeled-VMEM flat-vs-blocked choice
-  (threshold overridable via ``REPRO_SPMV_VMEM_LIMIT_BYTES`` or argument);
+  (threshold overridable via ``REPRO_SPMV_VMEM_LIMIT_BYTES`` or argument)
+  and, under ``auto``, the flat layout's diagonal-or-ELL local block
+  (:func:`diagonal_offsets`);
 * :func:`make_distributed_spmv` — build ``fn(x [P, in_pad]) -> y [P,
   row_pad]`` composing exchange + ELL matvec(s) for either layout.  With
   ``overlap=True`` the schedule is split: the exchange is issued first,
@@ -52,27 +64,39 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Callable, Optional, Union
+from typing import Callable, Optional, Tuple, Union
 
 import numpy as np
 
 from ..kernels.spmv_ell import DEFAULT_BLOCK_COLS, DEFAULT_BLOCK_ROWS
+from ..obs import default_obs
 from .csr import CSR
 from .partition import PartitionedCSR
+
+_M_NNZ = default_obs().counter(
+    "sparse/spmv_nnz",
+    "stored SpMV nonzeros set up, by the layout that applies them")
 
 
 @dataclass
 class DeviceEll:
-    """Stacked per-process padded-ELL blocks of a partitioned operator."""
+    """Stacked per-process padded-ELL blocks of a partitioned operator.
+
+    With ``offsets`` set the local block is stored by diagonals:
+    ``local_vals [P, D, row_pad]``, entry ``[p, d, i]`` the coefficient of
+    local column ``i + offsets[d]`` in row ``i`` (0.0 where that column is
+    outside the block, and in padded rows), and ``local_cols`` is None.
+    """
 
     n_procs: int
     row_pad: int     # uniform padded rows per process (== output vector pad)
     in_pad: int      # uniform padded input-vector block size
     ghost_pad: int   # uniform padded ghost count (0 => no exchange needed)
-    local_cols: np.ndarray   # [P, row_pad, Kl] int32; pad -> in_pad sentinel
-    local_vals: np.ndarray   # [P, row_pad, Kl]
+    local_cols: Optional[np.ndarray]  # [P, row_pad, Kl] int32; pad -> in_pad
+    local_vals: np.ndarray   # [P, row_pad, Kl], or [P, D, row_pad] diagonal
     ghost_cols: np.ndarray   # [P, row_pad, Kg] int32; pad -> ghost_pad
     ghost_vals: np.ndarray   # [P, row_pad, Kg]
+    offsets: Optional[Tuple[int, ...]] = None  # ascending; diagonal layout
 
 
 def _ell_block(
@@ -95,26 +119,63 @@ def partitioned_to_ell(part: PartitionedCSR, dtype=np.float64) -> DeviceEll:
     output of the matvec IS the next op's input vector — no repacking
     between levels of a solve.
     """
-    P_ = part.n_procs
     row_pad = int(np.diff(part.offsets).max())
     in_pad = int(np.diff(part.col_offsets).max())
     ghost_pad = int(max((len(n) for n in part.needs), default=0))
-    Kl = max(
-        max((int(np.diff(m.indptr).max()) for m in part.local if m.nnz),
-            default=0), 1,
-    )
-    Kg = max(
-        max((int(np.diff(m.indptr).max()) for m in part.ghost if m.nnz),
-            default=0), 1,
-    )
-    lc = np.empty((P_, row_pad, Kl), dtype=np.int32)
-    lv = np.empty((P_, row_pad, Kl), dtype=dtype)
-    gc = np.empty((P_, row_pad, Kg), dtype=np.int32)
-    gv = np.empty((P_, row_pad, Kg), dtype=dtype)
-    for p in range(P_):
-        lc[p], lv[p] = _ell_block(part.local[p], row_pad, Kl, in_pad, dtype)
-        gc[p], gv[p] = _ell_block(part.ghost[p], row_pad, Kg, ghost_pad, dtype)
-    return DeviceEll(P_, row_pad, in_pad, ghost_pad, lc, lv, gc, gv)
+    lc, lv = _stack_ell(part.local, row_pad, in_pad, dtype)
+    gc, gv = _stack_ell(part.ghost, row_pad, ghost_pad, dtype)
+    return DeviceEll(part.n_procs, row_pad, in_pad, ghost_pad, lc, lv, gc, gv)
+
+
+def _stack_ell(blocks, row_pad: int, pad_col: int, dtype) -> tuple:
+    """``[P, row_pad, K]`` cols/vals of the blocks, K their widest row."""
+    K = max(max((int(np.diff(m.indptr).max()) for m in blocks if m.nnz),
+                default=0), 1)
+    cols = np.empty((len(blocks), row_pad, K), dtype=np.int32)
+    vals = np.empty((len(blocks), row_pad, K), dtype=dtype)
+    for p, m in enumerate(blocks):
+        cols[p], vals[p] = _ell_block(m, row_pad, K, pad_col, dtype)
+    return cols, vals
+
+
+def diagonal_offsets(part: PartitionedCSR) -> Optional[Tuple[int, ...]]:
+    """The ascending column-minus-row offsets of a square partition's local
+    blocks, taken over all processes; None for a rectangular one.
+
+    O(nnz): one ``bincount`` per block over ``offset + row_pad``.
+    """
+    if not np.array_equal(part.offsets, part.col_offsets):
+        return None
+    row_pad = int(np.diff(part.offsets).max())
+    seen = np.zeros(2 * row_pad + 1, dtype=bool)
+    for m in part.local:
+        if m.nnz:
+            off = m.indices - m.row_indices() + row_pad
+            seen |= np.bincount(off, minlength=len(seen)) > 0
+    return tuple(int(o) for o in np.flatnonzero(seen) - row_pad)
+
+
+def partitioned_to_dia(
+    part: PartitionedCSR, offsets: Tuple[int, ...], dtype=np.float64
+) -> DeviceEll:
+    """Flat form whose local blocks are stored by the diagonals ``offsets``
+    (:func:`diagonal_offsets`); the ghost blocks as :func:`partitioned_to_ell`.
+
+    Each (row, offset) slot holds at most one entry: local blocks are
+    canonical CSR (``partitioned_from_blocks`` builds them with
+    ``CSR.from_coo``, which merges duplicates).
+    """
+    row_pad = int(np.diff(part.offsets).max())
+    ghost_pad = int(max((len(n) for n in part.needs), default=0))
+    off = np.asarray(offsets, dtype=np.int64)
+    vals = np.zeros((part.n_procs, len(off), row_pad), dtype=dtype)
+    for p, m in enumerate(part.local):
+        if m.nnz:
+            rows = m.row_indices()
+            vals[p, np.searchsorted(off, m.indices - rows), rows] = m.data
+    gc, gv = _stack_ell(part.ghost, row_pad, ghost_pad, dtype)
+    return DeviceEll(part.n_procs, row_pad, row_pad, ghost_pad, None, vals,
+                     gc, gv, offsets=tuple(offsets))
 
 
 @dataclass
@@ -308,19 +369,27 @@ def spmv_blocked_vmem_bytes(
 
 @dataclass(frozen=True)
 class KernelSelection:
-    """The flat-vs-blocked choice for one operator, recorded alongside the
-    plan's Section-5 transport choice so both selections are inspectable."""
+    """The flat-vs-blocked choice for one operator, and the flat layout's
+    diagonal-or-ELL local block, recorded alongside the plan's Section-5
+    transport choice so the selections are inspectable."""
 
     variant: str            # "flat" | "blocked"
     flat_bytes: int         # modeled flat footprint
     blocked_bytes: int      # modeled blocked footprint (bucket-K upper bound)
     limit_bytes: int        # threshold the choice was made against
     forced: bool = False    # True when the variant was pinned, not selected
+    offsets: Tuple[int, ...] = ()   # the local block's diagonals; () = ELL
+
+    @property
+    def local_layout(self) -> str:
+        return "diagonal" if self.offsets else "ell"
 
     def __str__(self) -> str:
         how = "forced" if self.forced else "auto"
+        local = (f"diagonal(D={len(self.offsets)})" if self.offsets
+                 else "ell")
         return (
-            f"kernel={self.variant} ({how}) "
+            f"kernel={self.variant} ({how}) local={local} "
             f"flat={self.flat_bytes / 2**10:.0f}KiB "
             f"blocked={self.blocked_bytes / 2**10:.0f}KiB "
             f"limit={self.limit_bytes / 2**10:.0f}KiB"
@@ -355,6 +424,12 @@ def select_spmv_kernel(
     kernel when it does not fit; ``"flat"``/``"blocked"`` pin the choice
     (recorded as forced).  The blocked estimate uses the max row width as a
     bucket-K upper bound — packing can only shrink it.
+
+    An ``auto`` flat choice stores the local block by diagonals when the
+    partition is square and its ``D`` offsets (:func:`diagonal_offsets`)
+    take no more bytes than the ELL width ``K`` they replace: ``D *
+    value_bytes <= K * (value_bytes + 4)``.  A pinned variant keeps the
+    ELL gather.
     """
     limit = (default_spmv_vmem_limit()
              if vmem_limit_bytes is None else int(vmem_limit_bytes))
@@ -371,9 +446,14 @@ def select_spmv_kernel(
         rows=row_pad, block_rows=block_rows, block_cols=block_cols,
     )
     if variant == "auto":
-        return KernelSelection(
-            "flat" if flat <= limit else "blocked", flat, blocked, limit
-        )
+        if flat > limit:
+            return KernelSelection("blocked", flat, blocked, limit)
+        offsets = diagonal_offsets(part)
+        if offsets and len(offsets) * value_bytes <= \
+                kl * (value_bytes + _IDX_BYTES):
+            return KernelSelection("flat", flat, blocked, limit,
+                                   offsets=offsets)
+        return KernelSelection("flat", flat, blocked, limit)
     if variant not in ("flat", "blocked"):
         raise ValueError(f"unknown spmv variant {variant!r}")
     return KernelSelection(variant, flat, blocked, limit, forced=True)
@@ -526,7 +606,16 @@ def partitioned_to_device(
     dtype=np.float64,
     block_cols: int = DEFAULT_BLOCK_COLS,
 ) -> Union[DeviceEll, "DeviceEllBlocked"]:
-    """Convert a partition to the device form the selection calls for."""
+    """Convert a partition to the device form the selection calls for,
+    counting its stored nonzeros in ``sparse/spmv_nnz`` by the layout that
+    applies them (a diagonal form's ghost block counts as ``ell``)."""
+    local_nnz = sum(m.nnz for m in part.local)
+    ghost_nnz = sum(m.nnz for m in part.ghost)
+    if selection.offsets:
+        _M_NNZ.inc(local_nnz, layout="diagonal")
+        _M_NNZ.inc(ghost_nnz, layout="ell")
+        return partitioned_to_dia(part, selection.offsets, dtype)
+    _M_NNZ.inc(local_nnz + ghost_nnz, layout="ell")
     if selection.variant == "blocked":
         return partitioned_to_ell_blocked(part, block_cols, dtype)
     return partitioned_to_ell(part, dtype)
@@ -564,9 +653,10 @@ def make_distributed_spmv(
 
     ``exchange`` is a bound plan executor (``NeighborAlltoallV.bind`` /
     ``PlanCache.executor``) mapping ``[P, in_pad, 1] -> [P, ghost_pad, 1]``;
-    required unless ``ell.ghost_pad == 0`` (fully local operator).  The
-    matvecs go through ``kernels.spmv_ell.ops`` and therefore dispatch to
-    the Pallas kernels on TPU and the jnp references on CPU.  A
+    required unless ``ell.ghost_pad == 0`` (fully local operator).  The ELL
+    matvecs go through ``kernels.spmv_ell.ops`` and so follow the platform's
+    ``kernels.IMPLS`` entry; a diagonal local block runs the jnp
+    ``spmv_dia`` body on every platform.  A
     :class:`DeviceEllBlocked` selects the column-blocked kernel: local and
     ghost values are concatenated into the bucketed gather space and one
     accumulating kernel covers both (ghost buckets trail, so halo-dependent
@@ -598,33 +688,47 @@ def make_distributed_spmv(
 
     from jax import shard_map
     from ..kernels.spmv_ell.ops import spmv
+    from ..kernels.spmv_ell.ref import spmv_dia
 
     if ell.ghost_pad and exchange is None:
         raise ValueError("operator has ghost columns: exchange required")
 
     spec = P(axis_name)
-    consts = [
-        jax.device_put(a, NamedSharding(mesh, spec))
-        for a in (ell.local_cols, ell.local_vals,
-                  ell.ghost_cols, ell.ghost_vals)
-    ]
+
+    def shard(a):
+        return jax.device_put(a, NamedSharding(mesh, spec))
+
+    # the local block's operands and product; blocks arrive with a leading
+    # device dim of 1
+    if ell.offsets is not None:
+        local = [shard(ell.local_vals)]
+
+        def local_mv(x, lv):
+            return spmv_dia(ell.offsets, lv[0], x)
+    else:
+        local = [shard(ell.local_cols), shard(ell.local_vals)]
+
+        def local_mv(x, lc, lv):
+            # sentinel slot at index in_pad
+            x = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
+            return spmv(lc[0], lv[0], x)
+    ghost = [shard(ell.ghost_cols), shard(ell.ghost_vals)]
+    nl = len(local)
     has_ghost = ell.ghost_pad > 0
 
+    def ghost_mv(gh, gc, gv):
+        gh = jnp.concatenate([gh, jnp.zeros((1,), gh.dtype)])
+        return spmv(gc[0], gv[0], gh)
+
     if overlap and has_ghost:
-        def per_device_local(x_blk, lc, lv):
-            x = jnp.concatenate(
-                [x_blk[0], jnp.zeros((1,), x_blk.dtype)]
-            )  # sentinel slot at index in_pad
-            return spmv(lc[0], lv[0], x)[None]
+        def per_device_local(x_blk, *lops):
+            return local_mv(x_blk[0], *lops)[None]
 
         def per_device_ghost(y_blk, gh_blk, gc, gv):
-            gh = jnp.concatenate(
-                [gh_blk[0], jnp.zeros((1,), gh_blk.dtype)]
-            )
-            return (y_blk[0] + spmv(gc[0], gv[0], gh))[None]
+            return (y_blk[0] + ghost_mv(gh_blk[0], gc, gv))[None]
 
         mm_local = shard_map(
-            per_device_local, mesh=mesh, in_specs=(spec,) * 3,
+            per_device_local, mesh=mesh, in_specs=(spec,) * (1 + nl),
             out_specs=spec, check_vma=False,
         )
         mm_ghost = shard_map(
@@ -634,28 +738,22 @@ def make_distributed_spmv(
 
         def spmv_fn(x):
             gh = exchange(x[..., None])[..., 0]   # issued before local work
-            y = mm_local(x, *consts[:2])          # no data dep on gh
-            return mm_ghost(y, gh, *consts[2:])
+            y = mm_local(x, *local)               # no data dep on gh
+            return mm_ghost(y, gh, *ghost)
 
         return jax.named_scope("spmv")(spmv_fn)
 
-    def per_device(x_blk, gh_blk, lc, lv, gc, gv):
-        # blocks arrive with a leading device dim of 1
-        x = jnp.concatenate(
-            [x_blk[0], jnp.zeros((1,), x_blk.dtype)]
-        )  # sentinel slot at index in_pad
-        y = spmv(lc[0], lv[0], x)
+    def per_device(x_blk, gh_blk, *ops):
+        y = local_mv(x_blk[0], *ops[:nl])
         if has_ghost:
-            gh = jnp.concatenate(
-                [gh_blk[0], jnp.zeros((1,), gh_blk.dtype)]
-            )
-            y = y + spmv(gc[0], gv[0], gh)
+            y = y + ghost_mv(gh_blk[0], *ops[nl:])
         return y[None]
 
+    consts = local + ghost
     mm = shard_map(
         per_device,
         mesh=mesh,
-        in_specs=(spec,) * 6,
+        in_specs=(spec,) * (2 + len(consts)),
         out_specs=spec,
         check_vma=False,
     )

@@ -430,17 +430,21 @@ def _multiset_equal(where: str, p: int, keys_a, vals_a, keys_b, vals_b,
 
 def verify_device_ell(ell, part) -> None:
     """Flat ELL carries exactly the partition's nonzeros, once each, with
-    padding entries pointing at the sentinel x slot with value zero."""
+    padding entries pointing at the sentinel x slot with value zero; a local
+    block stored by diagonals carries them at ``row + offsets[d]``."""
     if ell.row_pad != int(np.diff(part.offsets).max()):
         _fail("flat ELL row padding disagrees with the partition",
               row_pad=ell.row_pad)
     for p in range(part.n_procs):
-        for blk, cols, vals, width, what in (
-            (part.local[p], ell.local_cols[p], ell.local_vals[p],
-             ell.in_pad, "local"),
-            (part.ghost[p], ell.ghost_cols[p], ell.ghost_vals[p],
-             ell.ghost_pad, "ghost"),
-        ):
+        blocks = [(part.ghost[p], ell.ghost_cols[p], ell.ghost_vals[p],
+                   ell.ghost_pad, "ghost")]
+        if ell.offsets is None:
+            blocks.insert(0, (part.local[p], ell.local_cols[p],
+                              ell.local_vals[p], ell.in_pad, "local"))
+        else:
+            _verify_diagonal_block(part.local[p], ell.offsets,
+                                   ell.local_vals[p], p)
+        for blk, cols, vals, width, what in blocks:
             live = vals != 0
             if np.any(cols[live] >= blk.ncols):
                 r = int(np.argwhere(live & (cols >= blk.ncols))[0][0])
@@ -455,6 +459,25 @@ def verify_device_ell(ell, part) -> None:
             ck, cv = _csr_triples(blk)
             _multiset_equal(f"flat ELL {what} block", p, keys, vals[live],
                             ck, cv, "ell_nnz", "csr_nnz")
+
+
+def _verify_diagonal_block(blk, offsets, vals, p: int) -> None:
+    """``vals [D, row_pad]`` holds exactly the block's nonzeros, each at
+    ``[d, row]`` with ``col == row + offsets[d]`` inside the block."""
+    if vals.shape[0] != len(offsets) or list(offsets) != sorted(offsets):
+        _fail("diagonal block offsets are not ascending or disagree with "
+              "its values", rank=p, offsets=len(offsets),
+              diagonals=vals.shape[0])
+    live = vals != 0
+    d_idx, r_idx = np.nonzero(live)
+    cols = r_idx + np.asarray(offsets, dtype=np.int64)[d_idx]
+    if np.any((cols < 0) | (cols >= blk.ncols)) or \
+            np.any(r_idx >= blk.nrows):
+        _fail("diagonal entry outside the local block", rank=p)
+    keys = np.stack([r_idx.astype(np.int64), cols], 1)
+    ck, cv = _csr_triples(blk)
+    _multiset_equal("diagonal local block", p, keys, vals[live], ck, cv,
+                    "dia_nnz", "csr_nnz")
 
 
 def verify_ell_blocked(ell, part) -> None:

@@ -51,16 +51,25 @@ def flat_kernel_actual_bytes(
     buffered), x is a grid-constant ``(N, 1)`` block resident once
     (``N = pad + 1`` sentinel slot), and the output block is ``(br, 1)``.
     The two launches are summed with one shared output accumulator,
-    mirroring the estimator's both-resident assumption.
+    mirroring the estimator's both-resident assumption.  A local block
+    stored by diagonals is no kernel launch; its ``D`` diagonals stand in
+    for ``K`` here and in the estimate, which over-counts it.
     """
     br = min(int(block_rows), ell.row_pad) if ell.row_pad else int(block_rows)
-    kl = ell.local_cols.shape[2]
+    kl = _local_width(ell)
     kg = ell.ghost_cols.shape[2]
     x_local = (ell.in_pad + 1) * value_bytes
     x_ghost = (ell.ghost_pad + 1) * value_bytes if ell.ghost_pad else 0
     stream = 2 * br * (kl + kg) * (_IDX_BYTES + value_bytes)
     out = br * value_bytes
     return int(x_local + x_ghost + stream + out)
+
+
+def _local_width(ell) -> int:
+    """ELL width of a flat form's local block, or its number of diagonals."""
+    if ell.offsets is not None:
+        return len(ell.offsets)
+    return ell.local_cols.shape[2]
 
 
 def blocked_kernel_actual_bytes(
@@ -121,7 +130,7 @@ def verify_kernel_budget(
         )
         modeled = spmv_flat_vmem_bytes(
             in_pad=ell.in_pad, ghost_pad=ell.ghost_pad,
-            k_local=ell.local_cols.shape[2],
+            k_local=_local_width(ell),
             k_ghost=ell.ghost_cols.shape[2],
             value_bytes=value_bytes, rows=ell.row_pad,
             block_rows=block_rows,

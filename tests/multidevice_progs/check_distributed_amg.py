@@ -10,7 +10,9 @@ Checks, on the 64x64 rotated anisotropic diffusion problem:
      levels (fine -> standard, coarse -> aggregated);
   3. a second setup on the same hierarchy hits the plan cache only
      (no re-planning), and the bound executors are reused as-is;
-  4. the device distributed SpMV matches the host oracle on the fine level;
+  4. the device distributed SpMV matches the host oracle on the fine level,
+     with its local block gathered (ELL) and applied by diagonals; the
+     hierarchy stores the fine A's local block by diagonals, no other;
   5. measured device exchange times are finite and positive.
 """
 import os
@@ -51,8 +53,12 @@ def main():
     # (4) fine-level device SpMV vs host oracle
     part = partition_csr(h.levels[0].A, 8)
     coll = cache.collective(part.pattern, Topology(8, 4), "auto")
-    y_dev = distributed_spmv(part, coll, mesh, "proc", b)
-    np.testing.assert_allclose(y_dev, A.matvec(b), rtol=1e-12, atol=1e-12)
+    for variant in ("flat", "auto"):
+        y_dev = distributed_spmv(part, coll, mesh, "proc", b, variant=variant)
+        np.testing.assert_allclose(y_dev, A.matvec(b), rtol=1e-12,
+                                   atol=1e-12)
+    layouts = [lv.A.local_layout for lv in dh.levels]
+    assert layouts == ["diagonal"] + ["ell"] * (len(layouts) - 1), layouts
     print("spmv OK")
 
     # (1) residual histories match to 1e-8 relative tolerance

@@ -6,11 +6,13 @@ Run by tests/test_distributed_amg.py on 8 virtual host devices
 
 Checks, on the 48x48 rotated anisotropic diffusion problem:
   1. hierarchies with the overlapped schedule FORCED on every level — for
-     both the flat and the column-blocked kernel — solve to the host
-     solver's residual history (the split local-then-ghost accumulation is
-     numerically identical to the fused path);
+     the flat and the column-blocked kernel, and for the auto selection,
+     whose fine level stores its local block by diagonals — solve to the
+     host solver's residual history (the split local-then-ghost
+     accumulation is numerically identical to the fused path);
   2. the one-shot distributed SpMV agrees with the host oracle for every
-     kernel variant x overlap mode combination on the fine operator;
+     kernel variant (auto: diagonal local block) x overlap mode
+     combination on the fine operator;
   3. the default auto selection (off at this scale: local compute is below
      the split overhead) solves correctly and records its per-level
      decision on each operator;
@@ -48,7 +50,7 @@ def main():
     part = partition_csr(h.levels[0].A, 8)
     cache = PlanCache()
     coll = cache.collective(part.pattern, Topology(8, 4), "auto")
-    for variant in ("flat", "blocked"):
+    for variant in ("flat", "blocked", "auto"):
         for overlap in ("off", "on", "auto"):
             y = distributed_spmv(part, coll, mesh, "proc", b,
                                  variant=variant, block_cols=64,
@@ -58,11 +60,13 @@ def main():
     print("spmv variant x overlap grid OK")
 
     # (1) forced-overlap hierarchies match the host residual history
-    for variant in ("flat", "blocked"):
+    for variant in ("flat", "blocked", "auto"):
         dh = DistributedHierarchy.setup(
             h, mesh, procs_per_region=4, cache=PlanCache(),
             spmv_variant=variant, spmv_block_cols=64, spmv_overlap="on",
         )
+        fine = "diagonal" if variant == "auto" else "ell"
+        assert dh.levels[0].A.local_layout == fine, dh.levels[0].A.kernel
         ghosted = [lv for lv in dh.levels if lv.A.ell.ghost_pad > 0]
         assert ghosted, "test problem must have halo exchanges"
         for lv in ghosted:

@@ -37,6 +37,7 @@ def test_distributed_amg_vcycle_matches_host():
     assert "plan cache OK" in out
     # Section-5 selector: fine level standard, >=2 strategies over levels
     assert "A=standard" in out
+    assert out.count("local=diagonal") == 1
     assert "A=full" in out or "A=partial" in out
 
 
@@ -52,11 +53,13 @@ def test_blocked_spmv_hierarchy_matches_host():
 
 def test_overlap_spmv_hierarchy_matches_host():
     """Exchange/compute-overlapped schedule end to end: forced-overlap
-    hierarchies (flat + blocked kernels) track the host solver, auto
+    hierarchies (flat + blocked kernels, and the auto selection with the
+    fine level's diagonal local block) track the host solver, auto
     records its per-level decision, visible in describe()."""
     out = run_prog("check_overlap_spmv.py")
     assert "ALL_OK" in out
     assert "forced-overlap flat residual history OK" in out
     assert "forced-overlap blocked residual history OK" in out
+    assert "forced-overlap auto residual history OK" in out
     assert "auto-overlap residual history OK" in out
     assert "ov=off" in out

@@ -274,3 +274,94 @@ def test_row_block_bucket_map_structure():
     assert np.all(glists >= Cl)  # padding holds bucket_lo == Cl
     with pytest.raises(AssertionError):
         row_block_bucket_map(bell, bucket_lo=C)
+
+
+# ------------------------------------------------ diagonal local layout
+OPERATORS = {
+    "rotated7": lambda: diffusion_2d(24, 24),
+    "poisson5": lambda: diffusion_2d(24, 24, theta=0.0, eps=1.0),
+}
+SPLITS = {
+    "one": [0, 576],
+    "four_even": list(block_offsets(576, 4)),
+    "four_uneven": [0, 100, 250, 400, 576],   # padded rows on 3 of 4
+}
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+@pytest.mark.parametrize("split", sorted(SPLITS))
+@pytest.mark.parametrize("op", sorted(OPERATORS))
+def test_diagonal_layout_matches_ell_gather(op, split, overlap):
+    """The shifted-slice product of a stencil's diagonal local block equals
+    the ELL gather's to 1e-14 relative in f64, in the fused and the
+    overlapped schedule; padded rows stay exactly zero."""
+    import jax
+
+    from repro.core import PlanCache, Topology
+    from repro.sparse import make_distributed_spmv, partitioned_to_device
+    from repro.verify import verify_device_ell
+
+    A = OPERATORS[op]()
+    off = np.asarray(SPLITS[split])
+    P = len(off) - 1
+    part = partition_rect_csr(A, off, off)
+    sel = select_spmv_kernel(part)
+    assert sel.local_layout == "diagonal"
+    assert len(sel.offsets) == (7 if op == "rotated7" else 5)
+    dia = partitioned_to_device(part, sel)
+    verify_device_ell(dia, part)
+    ell = partitioned_to_ell(part)
+    mesh = jax.make_mesh((P,), ("proc",), devices=jax.devices()[:P])
+    coll = PlanCache().collective(part.pattern, Topology(P, min(P, 2)),
+                                  "standard")
+    exchange = coll.bind(mesh, "proc") if ell.ghost_pad else None
+    x = np.random.default_rng(4).normal(size=A.nrows)
+    with jax.enable_x64(True):
+        xg = pack_vector(off, ell.in_pad, x)
+        y_dia, y_ell = (
+            np.asarray(jax.jit(make_distributed_spmv(
+                form, mesh, "proc", exchange, overlap=overlap))(xg))
+            for form in (dia, ell))
+    assert y_dia.dtype == np.float64
+    scale = np.abs(y_ell).max()
+    np.testing.assert_allclose(y_dia, y_ell, rtol=0, atol=1e-14 * scale)
+    np.testing.assert_allclose(unpack_vector(off, y_dia), A.matvec(x),
+                               rtol=0, atol=1e-14 * scale)
+    for p in range(P):
+        np.testing.assert_array_equal(y_dia[p, off[p + 1] - off[p]:], 0.0)
+
+
+@pytest.mark.parametrize("n_procs", [1, 4])
+def test_diagonal_selection_on_rotated_hierarchy(n_procs):
+    """Only the stencil's fine A is banded enough for diagonals: every
+    coarse A, every R and P, and every pinned variant keep the ELL."""
+    h = build_hierarchy(diffusion_2d(24, 24))
+    for k, lv in enumerate(h.levels):
+        ops = [("A", lv.A, k, k)]
+        if lv.P is not None:
+            ops += [("R", lv.R, k + 1, k), ("P", lv.P, k, k + 1)]
+        for name, M, kr, kc in ops:
+            rows = h.levels[kr].A.nrows
+            cols = h.levels[kc].A.nrows
+            part = partition_rect_csr(M, block_offsets(rows, n_procs),
+                                      block_offsets(cols, n_procs))
+            sel = select_spmv_kernel(part)
+            want = "diagonal" if (k, name) == (0, "A") else "ell"
+            assert sel.local_layout == want, (k, name, str(sel))
+            assert sel.variant == "flat" and not sel.forced
+            assert f"local={want}" in str(sel)
+            for v in ("flat", "blocked"):
+                pinned = select_spmv_kernel(part, variant=v)
+                assert pinned.local_layout == "ell" and pinned.forced
+
+
+def test_diagonal_offsets_of_rectangular_partition_is_none():
+    from repro.sparse import diagonal_offsets
+
+    h = build_hierarchy(diffusion_2d(16, 16))
+    R = h.levels[0].R
+    part = partition_rect_csr(R, block_offsets(R.nrows, 2),
+                              block_offsets(R.ncols, 2))
+    assert diagonal_offsets(part) is None
+    sq = partition_csr(diffusion_2d(16, 16), 2)
+    assert diagonal_offsets(sq) == (-17, -16, -1, 0, 1, 16, 17)

@@ -192,3 +192,31 @@ def test_gc_pauses_are_spans():
              and e.attrs["generation"] == 2]
     assert ev.attrs["collected"] >= 0 and ev.duration >= 0
     assert obs.spans.totals["py/gc"][0] >= 1
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_spmv_nnz_counter_splits_by_layout(host_hierarchy, default_on,
+                                           n_dev):
+    """``sparse/spmv_nnz`` counts the fine A's local nonzeros under
+    ``layout=diagonal`` and every other stored nonzero under ``ell``; the
+    set-up's spans and ``describe()`` name the fine A's layout alone."""
+    dh = distributed(host_hierarchy, n_dev)
+    nnz = default_on.counter("sparse/spmv_nnz")
+    fine = dh.levels[0].A.part
+    diagonal = sum(m.nnz for m in fine.local)
+    stored = sum(m.nnz for lv in dh.levels for op in (lv.A, lv.R, lv.P)
+                 if op is not None for m in op.part.local + op.part.ghost)
+    assert nnz.value(layout="diagonal") == diagonal
+    assert nnz.value(layout="ell") == stored - diagonal
+    if n_dev == 1:
+        assert diagonal == host_hierarchy.levels[0].A.nnz
+    layouts = [lv.A.local_layout for lv in dh.levels]
+    assert layouts == ["diagonal"] + ["ell"] * (len(dh.levels) - 1)
+    assert all(op.local_layout == "ell" for lv in dh.levels
+               for op in (lv.R, lv.P) if op is not None)
+    built = [ev.attrs["layout"] for ev in default_on.spans.events(kind="span")
+             if ev.name == "amg/build_level"]
+    assert built == layouts
+    desc = dh.describe()
+    assert desc.count("local=diagonal") == 1 and "  L0:" in desc
+    assert "local=diagonal" in desc.splitlines()[1]

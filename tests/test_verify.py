@@ -160,6 +160,20 @@ def test_ell_rejects_moved_nonzero():
         verify_device_ell(ell, part)
 
 
+def test_diagonal_layout_accepts_and_rejects_altered_nonzero():
+    from repro.amg import diffusion_2d
+    from repro.sparse import diagonal_offsets, partitioned_to_dia
+
+    part = partition_csr(diffusion_2d(12, 10), 3)
+    dia = partitioned_to_dia(part, diagonal_offsets(part))
+    verify_device_ell(dia, part)
+    verify_kernel_budget(dia, select_spmv_kernel(part))
+    d, i = np.argwhere(dia.local_vals[1] != 0)[0]
+    dia.local_vals[1, d, i] *= 2.0
+    with pytest.raises(VerifyError, match="rank=1"):
+        verify_device_ell(dia, part)
+
+
 def test_bucket_map_rejects_duplicated_bucket():
     part = small_partition()
     bell = partitioned_to_ell_blocked(part, block_cols=8)
