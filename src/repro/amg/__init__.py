@@ -1,4 +1,9 @@
-from .stencil import diffusion_2d, paper_problem, rotated_anisotropic_stencil
+from .stencil import (
+    diffusion_2d,
+    laplacian_27pt,
+    paper_problem,
+    rotated_anisotropic_stencil,
+)
 from .coarsen import direct_interpolation, pmis, strength_graph
 from .hierarchy import Hierarchy, Level, build_hierarchy, jacobi, solve, v_cycle
 from .distributed import DistOp, DistributedHierarchy, DistributedLevel
@@ -11,7 +16,8 @@ from .distributed_setup import (
 )
 
 __all__ = [
-    "diffusion_2d", "paper_problem", "rotated_anisotropic_stencil",
+    "diffusion_2d", "laplacian_27pt", "paper_problem",
+    "rotated_anisotropic_stencil",
     "direct_interpolation", "pmis", "strength_graph",
     "Hierarchy", "Level", "build_hierarchy", "jacobi", "solve", "v_cycle",
     "DistOp", "DistributedHierarchy", "DistributedLevel",

@@ -73,6 +73,10 @@ from .distributed_setup import (
 from .hierarchy import Hierarchy, inv_diag
 
 _OBS = default_obs()
+_M_HALO = _OBS.counter(
+    "amg/halo_values",
+    "values an operator's exchange plan moves in one application, summed "
+    "over its steps and the chips, by level and operator")
 
 
 @dataclass
@@ -111,6 +115,14 @@ class DistOp:
     def overlap_mode(self) -> str:
         return self.overlap.mode if self.overlap else "off"
 
+    @property
+    def halo_values(self) -> int:
+        """Values the plan's messages carry in one application, summed over
+        its steps and the chips (a value relayed twice counts twice)."""
+        stats = self.coll.plan.stats
+        t = stats.totals()
+        return (t["intra_bytes"] + t["inter_bytes"]) // stats.value_bytes
+
 
 @dataclass
 class DistributedLevel:
@@ -129,6 +141,15 @@ def _default_procs_per_region(n_procs: int) -> int:
         if n_procs % ppr == 0 and n_procs > ppr:
             return ppr
     return 1
+
+
+def _count_halo_values(dl: DistributedLevel) -> None:
+    """``amg/halo_values`` of the level's A, R and P (obs on only)."""
+    if not _OBS.enabled:
+        return
+    for name, op in (("A", dl.A), ("R", dl.R), ("P", dl.P)):
+        if op is not None:
+            _M_HALO.inc(op.halo_values, level=dl.index, op=name)
 
 
 class DistributedHierarchy:
@@ -284,6 +305,7 @@ class DistributedHierarchy:
                         dl.R = make_op(lvl.R, offs[k + 1], offs[k])
                         dl.P = make_op(lvl.P, offs[k], offs[k + 1])
                     levels.append(dl)
+                    _count_halo_values(dl)
                     lsp.set(strategy=A_op.strategy,
                             kernel=A_op.kernel_variant,
                             layout=A_op.local_layout,
@@ -384,6 +406,7 @@ class DistributedHierarchy:
                         dl.P = make_op(sl.P_blocks, sl.row_offsets,
                                        sl.coarse_offsets)
                     levels.append(dl)
+                    _count_halo_values(dl)
                     lsp.set(strategy=A_op.strategy,
                             kernel=A_op.kernel_variant,
                             layout=A_op.local_layout,

@@ -1,11 +1,20 @@
-"""Rotated anisotropic diffusion operator (the paper's test problem).
+"""Stencil operators on regular grids: the paper's test problem and
+hypre's 3-D 27-point Laplacian.
 
--div(K grad u) on a regular 2-D grid, K = Q(theta)^T diag(1, eps) Q(theta),
-discretized with the classical 7-point finite-difference stencil for
-operators with mixed derivatives: center, E, W, N, S and the two corners
-along the strong-coupling diagonal (NE/SW for positive cross term).  At
-theta=45 deg this is exactly the paper's "7-point rotated anisotropic
-diffusion system" (rotation 45 deg, anisotropy 0.001).
+:func:`diffusion_2d`: -div(K grad u) on a regular 2-D grid, K =
+Q(theta)^T diag(1, eps) Q(theta), discretized with the classical 7-point
+finite-difference stencil for operators with mixed derivatives: center,
+E, W, N, S and the two corners along the strong-coupling diagonal (NE/SW
+for positive cross term).  At theta=45 deg this is exactly the paper's
+"7-point rotated anisotropic diffusion system" (rotation 45 deg,
+anisotropy 0.001); :func:`paper_problem` is its 524,288-row instance.
+
+:func:`laplacian_27pt`: hypre's ``GenerateLaplacian27pt`` (the ``ij -27pt``
+test driver): 26 on the diagonal, -1 for each of the 26 neighbours in the
+surrounding 3 x 3 x 3 cube, on a grid of ``px x py x pz`` process blocks of
+``m^3`` points each, rows numbered block by block as hypre's ParCSR numbers
+them.  Contiguous row blocks (``sparse.partition.block_offsets``) then give
+each process exactly its cube.
 """
 from __future__ import annotations
 
@@ -69,3 +78,41 @@ def paper_problem(rows: int = 524_288) -> CSR:
     ny = rows // nx
     assert nx * ny == rows, (nx, ny, rows)
     return diffusion_2d(ny, nx)
+
+
+def laplacian_27pt(m: int, procs=(1, 1, 1)) -> CSR:
+    """hypre's 27-point Laplacian (Dirichlet) on ``procs = (px, py, pz)``
+    blocks of ``m x m x m`` points, in hypre's process-block numbering.
+
+    Row of the point at local ``(lx, ly, lz)`` in block ``(bx, by, bz)``:
+    ``block * m^3 + (lz * m + ly) * m + lx`` with ``block = (bz * py + by)
+    * px + bx``.  Neighbours outside the global grid are dropped; the
+    diagonal stays 26, so interior rows sum to zero.
+    """
+    px, py, pz = (int(p) for p in procs)
+    nx, ny, nz = px * m, py * m, pz * m
+    # hypre row of each point of the global grid, indexed [z, y, x]
+    z, y, x = np.meshgrid(np.arange(nz), np.arange(ny), np.arange(nx),
+                          indexing="ij")
+    block = ((z // m) * py + y // m) * px + x // m
+    row_of = block * m ** 3 + ((z % m) * m + y % m) * m + x % m
+    rows_list, cols_list, vals_list = [], [], []
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                dst = tuple(slice(max(-d, 0), n - max(d, 0))
+                            for d, n in ((dz, nz), (dy, ny), (dx, nx)))
+                src = tuple(slice(max(d, 0), n + min(d, 0))
+                            for d, n in ((dz, nz), (dy, ny), (dx, nx)))
+                rows = row_of[dst].ravel()
+                rows_list.append(rows)
+                cols_list.append(row_of[src].ravel())
+                centre = dz == dy == dx == 0
+                vals_list.append(np.full(len(rows), 26.0 if centre else -1.0))
+    n = nx * ny * nz
+    return CSR.from_coo(
+        np.concatenate(rows_list),
+        np.concatenate(cols_list),
+        np.concatenate(vals_list),
+        (n, n),
+    )

@@ -76,6 +76,10 @@ from .partition import PartitionedCSR
 _M_NNZ = default_obs().counter(
     "sparse/spmv_nnz",
     "stored SpMV nonzeros set up, by the layout that applies them")
+_M_GHOST = default_obs().counter(
+    "sparse/ghost_slots",
+    "ghost-block slots set up: stored by the padded layout, and the entries "
+    "(nonzeros) among them")
 
 
 @dataclass
@@ -124,7 +128,18 @@ def partitioned_to_ell(part: PartitionedCSR, dtype=np.float64) -> DeviceEll:
     ghost_pad = int(max((len(n) for n in part.needs), default=0))
     lc, lv = _stack_ell(part.local, row_pad, in_pad, dtype)
     gc, gv = _stack_ell(part.ghost, row_pad, ghost_pad, dtype)
+    _count_ghost_slots(part, gc.size)
     return DeviceEll(part.n_procs, row_pad, in_pad, ghost_pad, lc, lv, gc, gv)
+
+
+def _count_ghost_slots(part: PartitionedCSR, stored: int) -> None:
+    """``sparse/ghost_slots``: the ``stored`` slots of a partition's ghost
+    layout and the ghost ``entries`` they hold; nothing for a partition with
+    no ghosts."""
+    entries = sum(m.nnz for m in part.ghost)
+    if entries:
+        _M_GHOST.inc(stored, kind="stored")
+        _M_GHOST.inc(entries, kind="entries")
 
 
 def _stack_ell(blocks, row_pad: int, pad_col: int, dtype) -> tuple:
@@ -174,6 +189,7 @@ def partitioned_to_dia(
             rows = m.row_indices()
             vals[p, np.searchsorted(off, m.indices - rows), rows] = m.data
     gc, gv = _stack_ell(part.ghost, row_pad, ghost_pad, dtype)
+    _count_ghost_slots(part, gc.size)
     return DeviceEll(part.n_procs, row_pad, row_pad, ghost_pad, None, vals,
                      gc, gv, offsets=tuple(offsets))
 
@@ -283,6 +299,7 @@ def partitioned_to_ell_blocked(
         slot = buckets * K + pos
         cols[p, rows, slot] = incols
         vals_out[p, rows, slot] = vals
+    _count_ghost_slots(part, P_ * row_pad * Cg * K)
     return DeviceEllBlocked(
         P_, row_pad, in_pad, ghost_pad, bc, Cl, Cg, K, cols, vals_out,
         bucket_K,
