@@ -122,7 +122,7 @@ def main():
     for lvl, op, strat, rep in dh.selection_table():
         if op == "A" and rep:
             print(f"  L{lvl} {op}: {rep}")
-    for lvl, op, variant, ov, rep in dh.kernel_table():
+    for lvl, op, variant, ghost, ov, rep in dh.kernel_table():
         if op == "A" and rep:
             print(f"  L{lvl} {op}: {rep}")
     if n_dev > 1:

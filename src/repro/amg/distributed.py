@@ -112,6 +112,10 @@ class DistOp:
         return self.kernel.local_layout if self.kernel else "ell"
 
     @property
+    def ghost_layout(self) -> str:
+        return self.ell.ghost_layout
+
+    @property
     def overlap_mode(self) -> str:
         return self.overlap.mode if self.overlap else "off"
 
@@ -309,6 +313,7 @@ class DistributedHierarchy:
                     lsp.set(strategy=A_op.strategy,
                             kernel=A_op.kernel_variant,
                             layout=A_op.local_layout,
+                            ghost=A_op.ghost_layout,
                             overlap=A_op.overlap_mode)
             dh = cls(levels, mesh, axis_name, topo, cache, dtype,
                      strategy, params, value_bytes,
@@ -410,6 +415,7 @@ class DistributedHierarchy:
                     lsp.set(strategy=A_op.strategy,
                             kernel=A_op.kernel_variant,
                             layout=A_op.local_layout,
+                            ghost=A_op.ghost_layout,
                             overlap=A_op.overlap_mode)
             dh = cls(levels, mesh, axis_name, topo, cache, dtype,
                      strategy, params, value_bytes,
@@ -796,12 +802,13 @@ class DistributedHierarchy:
 
     def kernel_table(
         self,
-    ) -> List[Tuple[int, str, str, str, Optional[str]]]:
-        """[(level, op, kernel variant, overlap mode, selection report)] —
-        the flat-vs-blocked SpMV choice and the exchange/compute-overlap
-        choice per operator, mirroring :meth:`selection_table` for the
-        transport choice; the report names the local layout
-        (``local=ell`` or ``local=diagonal(D=...)``)."""
+    ) -> List[Tuple[int, str, str, str, str, Optional[str]]]:
+        """[(level, op, kernel variant, ghost layout, overlap mode,
+        selection report)] — the flat-vs-blocked SpMV choice, the ghost
+        block's form (``compact``, ``rows`` or ``none``) and the
+        exchange/compute-overlap choice per operator, mirroring
+        :meth:`selection_table` for the transport choice; the report names
+        the local layout (``local=ell`` or ``local=diagonal(D=...)``)."""
         rows = []
         for lv in self.levels:
             for name, op in (("A", lv.A), ("R", lv.R), ("P", lv.P)):
@@ -809,9 +816,8 @@ class DistributedHierarchy:
                     continue
                 reps = [str(s) for s in (op.kernel, op.overlap) if s]
                 rep = "; ".join(reps) if reps else None
-                rows.append(
-                    (lv.index, name, op.kernel_variant, op.overlap_mode, rep)
-                )
+                rows.append((lv.index, name, op.kernel_variant,
+                             op.ghost_layout, op.overlap_mode, rep))
         return rows
 
     def describe(self) -> str:
@@ -826,6 +832,7 @@ class DistributedHierarchy:
                 f"  L{lv.index}: n={lv.n:>8,d} pad={lv.pad:>6d} "
                 f"A={lv.A.strategy:8s} kern={lv.A.kernel_variant:7s} "
                 f"local={lv.A.local_layout:8s} "
+                f"ghost={lv.A.ghost_layout:7s} "
                 f"ov={lv.A.overlap_mode:4s} "
                 f"inter_msgs={t['inter_msgs']:5d} "
                 f"inter_bytes={t['inter_bytes']:8d}"

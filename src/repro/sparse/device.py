@@ -23,7 +23,19 @@ Two device layouts, selected per operator by VMEM footprint
   row_pad]`` over the static ``offsets`` and applied as ``D`` shifted dense
   slices of a zero-padded x (``kernels.spmv_ell.spmv_dia``): no gather.
   Stencil fine levels pass; Galerkin coarse levels, R and P fail by orders
-  of magnitude and keep the gather.  The ghost block stays ELL.
+  of magnitude and keep the gather.
+
+  The ghost block is ELL over the rows that hold a ghost entry.  Where at
+  most half of an operator's rows hold one on every process (``2 * Rg <=
+  row_pad``, ``Rg`` the largest such count), it is stored **compact**:
+  ``ghost_rows [P, Rg]`` lists each process's ghost rows in ascending
+  order (a process with fewer lists distinct ghost-free rows too, whose
+  slots are all padding), ``ghost_cols/ghost_vals [P, Rg, Kg]`` hold
+  those rows' slots, and the ghost product over ``Rg`` rows is added back
+  into ``y`` at ``ghost_rows``.  Otherwise nothing would be skipped and
+  the block stays row-aligned ``[P, row_pad, Kg]`` (``ghost_rows``
+  None).  Either way each row adds its ghost sum, taken in the same slot
+  order, to its local sum, 0.0 where it holds no ghost.
 
 * **column-blocked** (:class:`DeviceEllBlocked`): each row's nonzeros are
   reordered into column buckets of ``block_cols`` x entries; local columns
@@ -80,6 +92,10 @@ _M_GHOST = default_obs().counter(
     "sparse/ghost_slots",
     "ghost-block slots set up: stored by the padded layout, and the entries "
     "(nonzeros) among them")
+_M_GHOST_ROWS = default_obs().counter(
+    "sparse/ghost_rows",
+    "ghost-block rows set up: stored by the compact layouts, and all the "
+    "padded rows of every operator with ghosts")
 
 
 @dataclass
@@ -98,21 +114,37 @@ class DeviceEll:
     ghost_pad: int   # uniform padded ghost count (0 => no exchange needed)
     local_cols: Optional[np.ndarray]  # [P, row_pad, Kl] int32; pad -> in_pad
     local_vals: np.ndarray   # [P, row_pad, Kl], or [P, D, row_pad] diagonal
-    ghost_cols: np.ndarray   # [P, row_pad, Kg] int32; pad -> ghost_pad
-    ghost_vals: np.ndarray   # [P, row_pad, Kg]
+    ghost_cols: np.ndarray   # [P, row_pad or Rg, Kg] int32; pad -> ghost_pad
+    ghost_vals: np.ndarray   # [P, row_pad or Rg, Kg]
     offsets: Optional[Tuple[int, ...]] = None  # ascending; diagonal layout
+    # [P, Rg] int32, ascending: the rows the compact ghost block holds;
+    # None when it is row-aligned
+    ghost_rows: Optional[np.ndarray] = None
+
+    @property
+    def ghost_layout(self) -> str:
+        """``compact``, ``rows`` (row-aligned) or ``none`` (no ghosts)."""
+        if not self.ghost_pad:
+            return "none"
+        return "rows" if self.ghost_rows is None else "compact"
 
 
 def _ell_block(
-    m: CSR, row_pad: int, K: int, pad_col: int, dtype
+    m: CSR, row_pad: int, K: int, pad_col: int, dtype,
+    rows: Optional[np.ndarray] = None,
 ) -> tuple:
+    """``[row_pad, K]`` cols/vals of ``m``'s rows, or of the ascending
+    ``rows`` alone (every entry of ``m`` on one of them), each row's
+    entries in CSR order."""
     cols = np.full((row_pad, K), pad_col, dtype=np.int32)
     vals = np.zeros((row_pad, K), dtype=dtype)
     if m.nnz:
-        rows = m.row_indices()
-        pos = np.arange(m.nnz, dtype=np.int64) - m.indptr[rows]
-        cols[rows, pos] = m.indices
-        vals[rows, pos] = m.data
+        r = m.row_indices()
+        pos = np.arange(m.nnz, dtype=np.int64) - m.indptr[r]
+        if rows is not None:
+            r = np.searchsorted(rows, r)
+        cols[r, pos] = m.indices
+        vals[r, pos] = m.data
     return cols, vals
 
 
@@ -127,30 +159,63 @@ def partitioned_to_ell(part: PartitionedCSR, dtype=np.float64) -> DeviceEll:
     in_pad = int(np.diff(part.col_offsets).max())
     ghost_pad = int(max((len(n) for n in part.needs), default=0))
     lc, lv = _stack_ell(part.local, row_pad, in_pad, dtype)
-    gc, gv = _stack_ell(part.ghost, row_pad, ghost_pad, dtype)
-    _count_ghost_slots(part, gc.size)
-    return DeviceEll(part.n_procs, row_pad, in_pad, ghost_pad, lc, lv, gc, gv)
+    gr, gc, gv = _stack_ghost(part, row_pad, ghost_pad, dtype)
+    return DeviceEll(part.n_procs, row_pad, in_pad, ghost_pad, lc, lv, gc, gv,
+                     ghost_rows=gr)
 
 
-def _count_ghost_slots(part: PartitionedCSR, stored: int) -> None:
+def _count_ghost_block(part: PartitionedCSR, stored: int,
+                       row_pad: int, rows: int = 0) -> None:
     """``sparse/ghost_slots``: the ``stored`` slots of a partition's ghost
-    layout and the ghost ``entries`` they hold; nothing for a partition with
-    no ghosts."""
+    layout and the ghost ``entries`` they hold; ``sparse/ghost_rows``: the
+    ``rows`` a compact ghost block stores and ``all`` of the partition's
+    padded rows.  Nothing for a partition with no ghosts."""
     entries = sum(m.nnz for m in part.ghost)
     if entries:
         _M_GHOST.inc(stored, kind="stored")
         _M_GHOST.inc(entries, kind="entries")
+        _M_GHOST_ROWS.inc(part.n_procs * row_pad, kind="all")
+        if rows:
+            _M_GHOST_ROWS.inc(rows, kind="stored")
 
 
-def _stack_ell(blocks, row_pad: int, pad_col: int, dtype) -> tuple:
-    """``[P, row_pad, K]`` cols/vals of the blocks, K their widest row."""
+def _stack_ell(blocks, row_pad: int, pad_col: int, dtype,
+               rows: Optional[np.ndarray] = None) -> tuple:
+    """``[P, row_pad, K]`` cols/vals of the blocks, K their widest row;
+    with ``rows [P, row_pad]`` block ``p``'s rows ``rows[p]`` alone."""
     K = max(max((int(np.diff(m.indptr).max()) for m in blocks if m.nnz),
                 default=0), 1)
     cols = np.empty((len(blocks), row_pad, K), dtype=np.int32)
     vals = np.empty((len(blocks), row_pad, K), dtype=dtype)
     for p, m in enumerate(blocks):
-        cols[p], vals[p] = _ell_block(m, row_pad, K, pad_col, dtype)
+        cols[p], vals[p] = _ell_block(
+            m, row_pad, K, pad_col, dtype,
+            None if rows is None else rows[p])
     return cols, vals
+
+
+def _stack_ghost(part: PartitionedCSR, row_pad: int, ghost_pad: int,
+                 dtype) -> tuple:
+    """``(ghost_rows, cols, vals)`` of a partition's ghost blocks: compact
+    over ``Rg`` rows where ``2 * Rg <= row_pad``, else row-aligned with
+    ``ghost_rows`` None (see the module docstring).  O(nnz + P row_pad)."""
+    live = [np.flatnonzero(np.diff(m.indptr)) for m in part.ghost]
+    Rg = max((len(r) for r in live), default=0)
+    if not Rg or 2 * Rg > row_pad:
+        gc, gv = _stack_ell(part.ghost, row_pad, ghost_pad, dtype)
+        _count_ghost_block(part, gc.size, row_pad)
+        return None, gc, gv
+    rows = np.empty((part.n_procs, Rg), dtype=np.int32)
+    for p, r in enumerate(live):
+        # pad with the first ghost-free rows: distinct, so no row is
+        # written twice
+        free = np.ones(row_pad, dtype=bool)
+        free[r] = False
+        rows[p] = np.sort(np.concatenate(
+            [r, np.flatnonzero(free)[: Rg - len(r)]]))
+    gc, gv = _stack_ell(part.ghost, Rg, ghost_pad, dtype, rows)
+    _count_ghost_block(part, gc.size, row_pad, rows.size)
+    return rows, gc, gv
 
 
 def diagonal_offsets(part: PartitionedCSR) -> Optional[Tuple[int, ...]]:
@@ -188,10 +253,9 @@ def partitioned_to_dia(
         if m.nnz:
             rows = m.row_indices()
             vals[p, np.searchsorted(off, m.indices - rows), rows] = m.data
-    gc, gv = _stack_ell(part.ghost, row_pad, ghost_pad, dtype)
-    _count_ghost_slots(part, gc.size)
+    gr, gc, gv = _stack_ghost(part, row_pad, ghost_pad, dtype)
     return DeviceEll(part.n_procs, row_pad, row_pad, ghost_pad, None, vals,
-                     gc, gv, offsets=tuple(offsets))
+                     gc, gv, offsets=tuple(offsets), ghost_rows=gr)
 
 
 @dataclass
@@ -226,6 +290,11 @@ class DeviceEllBlocked:
     @property
     def x_len(self) -> int:
         return self.n_buckets * self.block_cols
+
+    @property
+    def ghost_layout(self) -> str:
+        """``rows`` (its ghost buckets span every row) or ``none``."""
+        return "rows" if self.ghost_pad else "none"
 
 
 def _bucket_positions(rows: np.ndarray, buckets: np.ndarray, n_buckets: int):
@@ -299,7 +368,7 @@ def partitioned_to_ell_blocked(
         slot = buckets * K + pos
         cols[p, rows, slot] = incols
         vals_out[p, rows, slot] = vals
-    _count_ghost_slots(part, P_ * row_pad * Cg * K)
+    _count_ghost_block(part, P_ * row_pad * Cg * K, row_pad)
     return DeviceEllBlocked(
         P_, row_pad, in_pad, ghost_pad, bc, Cl, Cg, K, cols, vals_out,
         bucket_K,
@@ -347,6 +416,7 @@ def spmv_flat_vmem_bytes(
     value_bytes: int = 8,
     rows: Optional[int] = None,
     block_rows: int = DEFAULT_BLOCK_ROWS,
+    ghost_rows: Optional[int] = None,
 ) -> int:
     """Modeled per-device VMEM residency of the flat SpMV path.
 
@@ -356,11 +426,14 @@ def spmv_flat_vmem_bytes(
     the design), so near the threshold the conservative assumption is that
     both x vectors and both double-buffered cols/vals streams are resident
     at once.  ``rows`` clamps the row block exactly like the kernel does
-    (``min(block_rows, R)``).
+    (``min(block_rows, R)``); ``ghost_rows`` clamps the ghost launch's
+    (a compact ghost block's ``Rg``; ``rows`` when not given, which
+    bounds it).
     """
     br = min(int(block_rows), int(rows)) if rows else int(block_rows)
+    brg = min(br, int(ghost_rows)) if ghost_rows else br
     x_bytes = (in_pad + 1 + ghost_pad + (1 if ghost_pad else 0)) * value_bytes
-    stream = 2 * br * (k_local + k_ghost) * (_IDX_BYTES + value_bytes)
+    stream = 2 * (br * k_local + brg * k_ghost) * (_IDX_BYTES + value_bytes)
     out = br * value_bytes
     return int(x_bytes + stream + out)
 
@@ -673,7 +746,8 @@ def make_distributed_spmv(
     required unless ``ell.ghost_pad == 0`` (fully local operator).  The ELL
     matvecs go through ``kernels.spmv_ell.ops`` and so follow the platform's
     ``kernels.IMPLS`` entry; a diagonal local block runs the jnp
-    ``spmv_dia`` body on every platform.  A
+    ``spmv_dia`` body on every platform.  A compact ghost block's product
+    covers its ``Rg`` rows and is added into ``y`` at ``ghost_rows``.  A
     :class:`DeviceEllBlocked` selects the column-blocked kernel: local and
     ghost values are concatenated into the bucketed gather space and one
     accumulating kernel covers both (ghost buckets trail, so halo-dependent
@@ -730,26 +804,36 @@ def make_distributed_spmv(
             x = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
             return spmv(lc[0], lv[0], x)
     ghost = [shard(ell.ghost_cols), shard(ell.ghost_vals)]
+    if ell.ghost_rows is not None:
+        ghost.append(shard(ell.ghost_rows))
     nl = len(local)
     has_ghost = ell.ghost_pad > 0
 
-    def ghost_mv(gh, gc, gv):
+    def add_ghost(y, gh, gc, gv, *gr):
+        """``y`` plus the ghost block's product: on every row, or, for a
+        compact block, on its rows ``gr`` (sorted, unique) alone."""
         gh = jnp.concatenate([gh, jnp.zeros((1,), gh.dtype)])
-        return spmv(gc[0], gv[0], gh)
+        yg = spmv(gc[0], gv[0], gh)
+        if not gr:
+            return y + yg
+        # placed into a zero vector, so every row adds what it added from
+        # the row-aligned block, +0.0 where it holds no ghost
+        return y + jnp.zeros_like(y).at[gr[0][0]].set(
+            yg, indices_are_sorted=True, unique_indices=True)
 
     if overlap and has_ghost:
         def per_device_local(x_blk, *lops):
             return local_mv(x_blk[0], *lops)[None]
 
-        def per_device_ghost(y_blk, gh_blk, gc, gv):
-            return (y_blk[0] + ghost_mv(gh_blk[0], gc, gv))[None]
+        def per_device_ghost(y_blk, gh_blk, *gops):
+            return add_ghost(y_blk[0], gh_blk[0], *gops)[None]
 
         mm_local = shard_map(
             per_device_local, mesh=mesh, in_specs=(spec,) * (1 + nl),
             out_specs=spec, check_vma=False,
         )
         mm_ghost = shard_map(
-            per_device_ghost, mesh=mesh, in_specs=(spec,) * 4,
+            per_device_ghost, mesh=mesh, in_specs=(spec,) * (2 + len(ghost)),
             out_specs=spec, check_vma=False,
         )
 
@@ -763,7 +847,7 @@ def make_distributed_spmv(
     def per_device(x_blk, gh_blk, *ops):
         y = local_mv(x_blk[0], *ops[:nl])
         if has_ghost:
-            y = y + ghost_mv(gh_blk[0], *ops[nl:])
+            y = add_ghost(y, gh_blk[0], *ops[nl:])
         return y[None]
 
     consts = local + ghost

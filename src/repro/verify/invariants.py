@@ -431,34 +431,65 @@ def _multiset_equal(where: str, p: int, keys_a, vals_a, keys_b, vals_b,
 def verify_device_ell(ell, part) -> None:
     """Flat ELL carries exactly the partition's nonzeros, once each, with
     padding entries pointing at the sentinel x slot with value zero; a local
-    block stored by diagonals carries them at ``row + offsets[d]``."""
+    block stored by diagonals carries them at ``row + offsets[d]``; a
+    compact ghost block's ``ghost_rows`` are ascending, unique, in range and
+    omit no row that holds a ghost entry."""
     if ell.row_pad != int(np.diff(part.offsets).max()):
         _fail("flat ELL row padding disagrees with the partition",
               row_pad=ell.row_pad)
+    ghost_rows = ell.ghost_rows
+    if ghost_rows is None:      # row-aligned: every padded row
+        ghost_rows = np.broadcast_to(np.arange(ell.row_pad),
+                                     (part.n_procs, ell.row_pad))
+    if ell.ghost_cols.shape[:2] != ghost_rows.shape:
+        _fail("ghost block's shape disagrees with the rows it stores",
+              rows=ghost_rows.shape, block=ell.ghost_cols.shape)
     for p in range(part.n_procs):
+        _verify_ghost_rows(part.ghost[p], ghost_rows[p], ell.row_pad, p)
         blocks = [(part.ghost[p], ell.ghost_cols[p], ell.ghost_vals[p],
-                   ell.ghost_pad, "ghost")]
+                   ell.ghost_pad, "ghost", ghost_rows[p])]
         if ell.offsets is None:
             blocks.insert(0, (part.local[p], ell.local_cols[p],
-                              ell.local_vals[p], ell.in_pad, "local"))
+                              ell.local_vals[p], ell.in_pad, "local",
+                              np.arange(ell.row_pad)))
         else:
             _verify_diagonal_block(part.local[p], ell.offsets,
                                    ell.local_vals[p], p)
-        for blk, cols, vals, width, what in blocks:
+        for blk, cols, vals, width, what, rows in blocks:
             live = vals != 0
             if np.any(cols[live] >= blk.ncols):
                 r = int(np.argwhere(live & (cols >= blk.ncols))[0][0])
                 _fail(f"flat ELL {what} entry references a column outside "
-                      "the block", rank=p, row=r)
+                      "the block", rank=p, row=int(rows[r]))
             if np.any(cols > width):
                 _fail(f"flat ELL {what} column index beyond the sentinel",
                       rank=p, sentinel=width)
             r_idx, c_idx = np.nonzero(live)
-            keys = np.stack([r_idx.astype(np.int64),
+            keys = np.stack([rows[r_idx].astype(np.int64),
                              cols[live].astype(np.int64)], 1)
             ck, cv = _csr_triples(blk)
             _multiset_equal(f"flat ELL {what} block", p, keys, vals[live],
                             ck, cv, "ell_nnz", "csr_nnz")
+
+
+def _verify_ghost_rows(blk, rows, row_pad: int, p: int) -> None:
+    """The rows a ghost block stores: strictly ascending (so unique), in
+    ``[0, row_pad)``, and every row of ``blk`` with an entry among them."""
+    rows = np.asarray(rows, dtype=np.int64)
+    if len(rows) and (rows[0] < 0 or rows[-1] >= row_pad):
+        _fail("ghost row outside the padded rows", rank=p,
+              row=int(rows[0] if rows[0] < 0 else rows[-1]),
+              row_pad=row_pad)
+    if np.any(np.diff(rows) <= 0):
+        i = int(np.argmax(np.diff(rows) <= 0))
+        _fail("ghost rows not ascending and unique (a row would be written "
+              "twice)", rank=p, row=int(rows[i + 1]))
+    held = np.flatnonzero(np.diff(blk.indptr))
+    omitted = np.setdiff1d(held, rows)
+    if len(omitted):
+        _fail("a row holding a ghost entry is omitted from the ghost rows "
+              "(its ghost products would be dropped)", rank=p,
+              row=int(omitted[0]))
 
 
 def _verify_diagonal_block(blk, offsets, vals, p: int) -> None:

@@ -50,17 +50,20 @@ def flat_kernel_actual_bytes(
     vals blocks are ``(br, K)`` and vary with the grid step (double
     buffered), x is a grid-constant ``(N, 1)`` block resident once
     (``N = pad + 1`` sentinel slot), and the output block is ``(br, 1)``.
-    The two launches are summed with one shared output accumulator,
-    mirroring the estimator's both-resident assumption.  A local block
-    stored by diagonals is no kernel launch; its ``D`` diagonals stand in
-    for ``K`` here and in the estimate, which over-counts it.
+    The ghost launch runs over the ghost block's own rows (``Rg`` of a
+    compact block), which clamp its ``br``.  The two launches are summed
+    with one shared output accumulator, mirroring the estimator's
+    both-resident assumption.  A local block stored by diagonals is no
+    kernel launch; its ``D`` diagonals stand in for ``K`` here and in the
+    estimate, which over-counts it.
     """
     br = min(int(block_rows), ell.row_pad) if ell.row_pad else int(block_rows)
+    brg = min(br, ell.ghost_cols.shape[1])
     kl = _local_width(ell)
     kg = ell.ghost_cols.shape[2]
     x_local = (ell.in_pad + 1) * value_bytes
     x_ghost = (ell.ghost_pad + 1) * value_bytes if ell.ghost_pad else 0
-    stream = 2 * br * (kl + kg) * (_IDX_BYTES + value_bytes)
+    stream = 2 * (br * kl + brg * kg) * (_IDX_BYTES + value_bytes)
     out = br * value_bytes
     return int(x_local + x_ghost + stream + out)
 
@@ -133,7 +136,7 @@ def verify_kernel_budget(
             k_local=_local_width(ell),
             k_ghost=ell.ghost_cols.shape[2],
             value_bytes=value_bytes, rows=ell.row_pad,
-            block_rows=block_rows,
+            block_rows=block_rows, ghost_rows=ell.ghost_cols.shape[1],
         )
         variant = "flat"
     if abs(modeled - actual) > rtol * max(actual, 1):

@@ -98,8 +98,8 @@ def main():
 
     # (4) the choice is recorded and visible
     kt = dh.kernel_table()
-    assert any(v == "blocked" for _, _, v, _, _ in kt)
-    assert all(rep and "limit=" in rep for _, _, _, _, rep in kt)
+    assert any(v == "blocked" for _, _, v, _, _, _ in kt)
+    assert all(rep and "limit=" in rep for *_, rep in kt)
     desc = dh.describe()
     assert "kern=blocked" in desc and "kern=flat" in desc
     print(desc)

@@ -12,7 +12,8 @@ Checks, on the 64x64 rotated anisotropic diffusion problem:
      (no re-planning), and the bound executors are reused as-is;
   4. the device distributed SpMV matches the host oracle on the fine level,
      with its local block gathered (ELL) and applied by diagonals; the
-     hierarchy stores the fine A's local block by diagonals, no other;
+     hierarchy stores the fine A's local block by diagonals, no other,
+     and the fine A's ghost block compact;
   5. measured device exchange times are finite and positive.
 """
 import os
@@ -59,6 +60,8 @@ def main():
                                    atol=1e-12)
     layouts = [lv.A.local_layout for lv in dh.levels]
     assert layouts == ["diagonal"] + ["ell"] * (len(layouts) - 1), layouts
+    # the fine A's ghost entries sit on its strips' boundary rows alone
+    assert dh.levels[0].A.ghost_layout == "compact"
     print("spmv OK")
 
     # (1) residual histories match to 1e-8 relative tolerance

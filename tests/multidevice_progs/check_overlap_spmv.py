@@ -98,8 +98,9 @@ def main():
 
     # (4) the decision is recorded and visible
     kt = dh.kernel_table()
-    assert all(ov in ("on", "off") for _, _, _, ov, _ in kt)
-    assert all("overlap=" in rep for _, _, _, _, rep in kt), kt
+    assert all(ov in ("on", "off") for _, _, _, _, ov, _ in kt)
+    assert all(g in ("compact", "rows", "none") for _, _, _, g, _, _ in kt)
+    assert all("overlap=" in rep for *_, rep in kt), kt
     desc = dh.describe()
     assert "ov=off" in desc
     print(desc)

@@ -38,6 +38,7 @@ def test_distributed_amg_vcycle_matches_host():
     # Section-5 selector: fine level standard, >=2 strategies over levels
     assert "A=standard" in out
     assert out.count("local=diagonal") == 1
+    assert "ghost=compact" in out.splitlines()[1]
     assert "A=full" in out or "A=partial" in out
 
 

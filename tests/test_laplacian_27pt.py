@@ -1,6 +1,9 @@
 """hypre's 3-D 27-point Laplacian (``ij -27pt``): the generator against a
 brute-force loop, a four-device solve against the host solver, and the
-set-up counters ``sparse/ghost_slots`` and ``amg/halo_values``."""
+set-up counters ``sparse/ghost_slots``, ``sparse/ghost_rows`` and
+``amg/halo_values``, and the compact ghost block's histories against the
+row-aligned block's."""
+import dataclasses
 import itertools
 
 import jax
@@ -142,6 +145,31 @@ def test_ghost_slot_and_halo_value_counters(host_27pt, default_on, variant):
     assert entries > 0
     assert slots.value(kind="entries") == entries
     assert slots.value(kind="stored") == stored >= entries
+    # sparse/ghost_rows: the rows compact blocks store, beside every row
+    # of the operators with ghosts
+    rows = default_on.counter("sparse/ghost_rows")
+    ghosted = [op for _, _, op in ops if op.ell.ghost_pad]
+    compact = [op for op in ghosted if op.ghost_layout == "compact"]
+    assert rows.value(kind="all") == sum(
+        4 * op.ell.row_pad for op in ghosted)
+    assert rows.value(kind="stored") == sum(
+        op.ell.ghost_rows.size for op in compact)
+    fine = dh.levels[0].A
+    if variant == "auto":
+        # the diagonal fine A and the ELL L0 R and P compact; each stores
+        # its ghost rows' slots alone
+        assert fine.local_layout == "diagonal"
+        assert {(k, name) for k, name, op in ops
+                if op.ghost_layout == "compact"} >= {
+            (0, "A"), (0, "R"), (0, "P")}
+        assert fine.ell.ghost_rows.shape == (4, 120)
+        assert fine.ell.ghost_cols.shape[:2] == (4, 120)
+        assert slots.value(kind="stored") < sum(
+            4 * op.ell.row_pad * op.ell.ghost_cols.shape[2]
+            for op in ghosted)
+    else:
+        assert not compact and rows.value(kind="stored") == 0
+        assert {op.ghost_layout for op in ghosted} == {"rows"}
     halo = default_on.counter("amg/halo_values")
     for k, name, op in ops:
         # every ghost value arrives once, some relayed through a region
@@ -157,4 +185,42 @@ def test_counters_record_nothing_with_obs_off(host_27pt):
     DistributedHierarchy.setup(host_27pt, mesh, dtype=np.float32,
                                value_bytes=4)
     assert obs.counter("sparse/ghost_slots").total() == 0
+    assert obs.counter("sparse/ghost_rows").total() == 0
     assert obs.counter("amg/halo_values").total() == 0
+
+
+def row_aligned(op):
+    """``op``'s device form with its ghost block row-aligned, as it is
+    stored where most rows hold a ghost."""
+    from repro.sparse.device import _stack_ell
+
+    ell = op.ell
+    gc, gv = _stack_ell(op.part.ghost, ell.row_pad, ell.ghost_pad,
+                        ell.ghost_vals.dtype)
+    return dataclasses.replace(ell, ghost_cols=gc, ghost_vals=gv,
+                               ghost_rows=None)
+
+
+@pytest.mark.parametrize("n_dev,overlap", [(1, "off"), (4, "off"),
+                                           (4, "on")])
+def test_compact_histories_equal_row_aligned(host_27pt, n_dev, overlap):
+    """The solve's residual history with compact ghost blocks equals, bit
+    for bit, the history with every ghost block row-aligned, in the fused
+    and the overlapped schedule; one device has no ghost block."""
+    mesh = jax.make_mesh((n_dev,), ("proc",), devices=jax.devices()[:n_dev])
+    b = np.random.default_rng(9).standard_normal(
+        host_27pt.levels[0].A.nrows)
+    with jax.enable_x64(True):
+        dh = DistributedHierarchy.setup(host_27pt, mesh,
+                                        spmv_overlap=overlap)
+        _, hist = dh.solve(b, tol=1e-8, max_iters=40)
+        ops = [op for lv in dh.levels for op in (lv.A, lv.R, lv.P)
+               if op is not None and op.ghost_layout == "compact"]
+        assert bool(ops) == (n_dev > 1)
+        assert all(op.overlap_mode == overlap for op in ops)
+        for op in ops:
+            op.ell = row_aligned(op)
+        dh._build_device_fns()
+        _, hist_rows = dh.solve(b, tol=1e-8, max_iters=40)
+    assert hist[-1] < 1e-8
+    assert hist == hist_rows

@@ -208,15 +208,22 @@ def test_p8_blocked_packing_matches_flat_and_ref(sp):
         if ell.ghost_pad:
             gf = np.zeros(ell.ghost_pad + 1, dtype=np.float32)
             gf[: len(ghosts[p])] = ghosts[p].astype(np.float32)
-            flat = flat + spmv_ell(
+
+            def on_rows(yg):
+                # a compact ghost block's rows land at ghost_rows
+                if ell.ghost_rows is None:
+                    return yg
+                return jnp.zeros(ell.row_pad, yg.dtype).at[
+                    ell.ghost_rows[p]].set(yg)
+            flat = flat + on_rows(spmv_ell(
                 jnp.asarray(ell.ghost_cols[p]),
                 jnp.asarray(ell.ghost_vals[p]),
                 jnp.asarray(gf), block_rows=8, interpret=True,
-            )
-            ref = ref + spmv_ell_ref(
+            ))
+            ref = ref + on_rows(spmv_ell_ref(
                 jnp.asarray(ell.ghost_cols[p]),
                 jnp.asarray(ell.ghost_vals[p]), jnp.asarray(gf),
-            )
+            ))
         # blocked: one accumulating kernel over [local | ghost] buckets
         xb = np.zeros(bell.x_len, dtype=np.float32)
         xb[: len(xs[p])] = xs[p]

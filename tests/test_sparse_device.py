@@ -32,6 +32,17 @@ def _ell_matvec(cols, vals, x_ext):
     return np.sum(vals * x_ext[cols], axis=1)
 
 
+def _ghost_matvec(ell, p, g_ext):
+    """Process ``p``'s ghost-block product on all ``row_pad`` rows: a
+    compact block's rows scattered to ``ghost_rows``."""
+    yg = _ell_matvec(ell.ghost_cols[p], ell.ghost_vals[p], g_ext)
+    if ell.ghost_rows is None:
+        return yg
+    y = np.zeros(ell.row_pad)
+    y[ell.ghost_rows[p]] = yg
+    return y
+
+
 def test_rect_partition_matches_serial_on_restriction():
     A = diffusion_2d(24, 18)
     h = build_hierarchy(A)
@@ -69,7 +80,7 @@ def test_partitioned_to_ell_reproduces_blocks():
         y = _ell_matvec(ell.local_cols[p], ell.local_vals[p], x_ext)
         g_ext = np.zeros(ell.ghost_pad + 1)
         g_ext[: len(ghosts[p])] = ghosts[p]
-        y = y + _ell_matvec(ell.ghost_cols[p], ell.ghost_vals[p], g_ext)
+        y = y + _ghost_matvec(ell, p, g_ext)
         want = part.local[p].matvec(xs[p])
         if part.ghost[p].ncols:
             want = want + part.ghost[p].matvec(ghosts[p])
@@ -365,3 +376,125 @@ def test_diagonal_offsets_of_rectangular_partition_is_none():
     assert diagonal_offsets(part) is None
     sq = partition_csr(diffusion_2d(16, 16), 2)
     assert diagonal_offsets(sq) == (-17, -16, -1, 0, 1, 16, 17)
+
+
+# ------------------------------------------------ compact ghost block
+def _fractions(n: int, fr) -> np.ndarray:
+    return np.round(np.asarray(fr) * n).astype(np.int64)
+
+
+def _compact_case(op: str, split: str):
+    """(operator, row offsets, column offsets) of a compact-ghost case."""
+    from repro.amg import laplacian_27pt
+
+    fr = {"even": [0, .25, .5, .75, 1], "uneven": [0, .17, .43, .7, 1]}[split]
+    if op == "rotated7":
+        M = diffusion_2d(24, 24)
+    elif op == "lap27":
+        M = laplacian_27pt(8, (2, 2, 1))
+    else:
+        lv = build_hierarchy(diffusion_2d(32, 32)).levels[0]
+        M = lv.R if op == "R" else lv.P
+    return M, _fractions(M.nrows, fr), _fractions(M.ncols, fr)
+
+
+def _row_aligned(ell, part):
+    """The same operator with its ghost block row-aligned, as it is stored
+    where most rows hold a ghost."""
+    import dataclasses
+
+    from repro.sparse.device import _stack_ell
+
+    gc, gv = _stack_ell(part.ghost, ell.row_pad, ell.ghost_pad,
+                        ell.ghost_vals.dtype)
+    return dataclasses.replace(ell, ghost_cols=gc, ghost_vals=gv,
+                               ghost_rows=None)
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+@pytest.mark.parametrize("split", ["even", "uneven"])
+@pytest.mark.parametrize("op", ["rotated7", "lap27", "R", "P"])
+def test_compact_ghost_block_matches_row_aligned(op, split, overlap):
+    """A compact ghost block (only the rows that hold a ghost, added back
+    at ``ghost_rows``) gives the row-aligned block's product bit for bit,
+    in the fused and the overlapped schedule, on 4 even and 4 uneven
+    blocks; the product matches the host CSR product to 1e-14 relative in
+    f64, and padded rows are exactly zero.
+
+    A block one slot wide is the exception: there XLA's CPU backend fuses
+    the row-aligned product's multiply into its add to ``y`` (one FMA),
+    which the compact form's scatter keeps apart, so the two agree to the
+    rounding of one product: one unit in the last place of the largest
+    entry."""
+    import jax
+
+    from repro.core import PlanCache, Topology
+    from repro.sparse import make_distributed_spmv, partitioned_to_device
+    from repro.verify import verify_device_ell
+
+    M, roff, coff = _compact_case(op, split)
+    part = partition_rect_csr(M, roff, coff)
+    ell = partitioned_to_device(part, select_spmv_kernel(part))
+    assert ell.ghost_layout == "compact"
+    Rg = ell.ghost_rows.shape[1]
+    assert ell.ghost_cols.shape[:2] == (4, Rg) and 2 * Rg <= ell.row_pad
+    verify_device_ell(ell, part)
+    rows = _row_aligned(ell, part)
+    assert rows.ghost_layout == "rows"
+    verify_device_ell(rows, part)
+    mesh = jax.make_mesh((4,), ("proc",), devices=jax.devices()[:4])
+    exchange = PlanCache().collective(
+        part.pattern, Topology(4, 2), "standard").bind(mesh, "proc")
+    x = np.random.default_rng(6).normal(size=M.ncols)
+    with jax.enable_x64(True):
+        xg = pack_vector(coff, ell.in_pad, x)
+        y, y_rows = (
+            np.asarray(jax.jit(make_distributed_spmv(
+                form, mesh, "proc", exchange, overlap=overlap))(xg))
+            for form in (ell, rows))
+    assert y.dtype == np.float64
+    if ell.ghost_cols.shape[2] > 1:
+        np.testing.assert_array_equal(y, y_rows)
+    else:
+        np.testing.assert_allclose(
+            y, y_rows, rtol=0,
+            atol=np.finfo(np.float64).eps * np.abs(y_rows).max())
+    want = M.matvec(x)
+    np.testing.assert_allclose(unpack_vector(roff, y), want, rtol=0,
+                               atol=1e-14 * np.abs(want).max())
+    for p in range(4):
+        np.testing.assert_array_equal(y[p, roff[p + 1] - roff[p]:], 0.0)
+
+
+def _tridiagonal(n: int):
+    from repro.sparse.csr import CSR
+
+    i = np.arange(n)
+    rows = np.concatenate([i, i[1:], i[:-1]])
+    cols = np.concatenate([i, i[1:] - 1, i[:-1] + 1])
+    vals = np.concatenate([np.full(n, 2.0), -np.ones(2 * (n - 1))])
+    return CSR.from_coo(rows, cols, vals, (n, n))
+
+
+@pytest.mark.parametrize("rows_a_block,want", [(4, "compact"), (3, "rows")])
+def test_ghost_block_compacts_where_at_most_half_the_rows_hold_one(
+        rows_a_block, want):
+    """A tridiagonal operator's inner blocks hold a ghost on their first
+    and last rows (``Rg = 2``): compact at 4 rows a block (``2 Rg <=
+    row_pad``), row-aligned at 3.  The end blocks, with one ghost row
+    each, list their first ghost-free row besides."""
+    A = _tridiagonal(4 * rows_a_block)
+    part = partition_csr(A, 4)
+    ell = partitioned_to_ell(part)
+    assert ell.row_pad == rows_a_block
+    assert ell.ghost_layout == want
+    if want == "compact":
+        last = rows_a_block - 1
+        np.testing.assert_array_equal(
+            ell.ghost_rows, [[0, last], [0, last], [0, last], [0, 1]])
+        np.testing.assert_array_equal(ell.ghost_vals[0, 0], 0.0)
+        np.testing.assert_array_equal(ell.ghost_vals[3, 1], 0.0)
+        assert ell.ghost_cols.shape == (4, 2, 1)
+    else:
+        assert ell.ghost_rows is None
+        assert ell.ghost_cols.shape == (4, rows_a_block, 1)

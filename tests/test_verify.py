@@ -160,6 +160,36 @@ def test_ell_rejects_moved_nonzero():
         verify_device_ell(ell, part)
 
 
+@pytest.mark.parametrize("fault,match", [
+    ("omitted", "omitted from the ghost rows"),
+    ("duplicated", "written twice"),
+    ("out_of_range", "outside the padded rows"),
+])
+def test_compact_ghost_rows_reject_planted_fault(fault, match):
+    """A compact ghost block's rows must be ascending, unique, in range
+    and cover every row that holds a ghost entry."""
+    from repro.amg import diffusion_2d
+
+    part = partition_csr(diffusion_2d(24, 8), 3)
+    ell = partitioned_to_ell(part)
+    assert ell.ghost_layout == "compact"
+    verify_device_ell(ell, part)
+    rows = ell.ghost_rows[1]
+    if fault == "omitted":
+        # move a ghost row's slots to a row the block does not list
+        rows[-1] = np.setdiff1d(np.arange(ell.row_pad), rows)[0]
+        order = np.argsort(rows)
+        ell.ghost_rows[1] = rows[order]
+        ell.ghost_cols[1] = ell.ghost_cols[1][order]
+        ell.ghost_vals[1] = ell.ghost_vals[1][order]
+    elif fault == "duplicated":
+        rows[1] = rows[0]
+    else:
+        rows[-1] = ell.row_pad
+    with pytest.raises(VerifyError, match=match):
+        verify_device_ell(ell, part)
+
+
 def test_diagonal_layout_accepts_and_rejects_altered_nonzero():
     from repro.amg import diffusion_2d
     from repro.sparse import diagonal_offsets, partitioned_to_dia
