@@ -1,5 +1,5 @@
 """Aggregate dry-run cell JSONs into the roofline table (EXPERIMENTS.md),
-plus a modeled SpMV kernel-variant roofline (flat vs column-blocked)."""
+plus a modeled SpMV kernel roofline."""
 from __future__ import annotations
 
 import glob
@@ -17,59 +17,26 @@ RESULTS = os.path.join(os.path.dirname(__file__), "results", "dryrun")
 # cells: labeled, never presented as measurements).
 
 
-def spmv_kernel_cells(
+def spmv_kernel_cell(
     rows_per_proc: int = 2 ** 21,
     k: int = 9,
     ghost: int = 2 * 4096,
     value_bytes: int = 8,
-    block_rows: int = 256,
-    block_cols: int = 512,
-) -> List[Dict]:
-    """Modeled roofline of both SpMV variants on a paper-scale fine level.
-
-    Flat reads x once (VMEM-resident — only legal when it fits); blocked
-    re-streams each x column block once per row block, trading HBM traffic
-    for a VMEM footprint independent of the x length.  Deterministic
-    arithmetic — gated by ``benchmarks.compare``.
+) -> Dict:
+    """Modeled roofline of the ELL SpMV on a paper-scale fine level: the
+    ELL streams, x read once and y written once.  Deterministic arithmetic
+    — gated by ``benchmarks.compare``.
     """
-    from repro.sparse.device import (
-        spmv_blocked_vmem_bytes,
-        spmv_flat_vmem_bytes,
-    )
-
     n = rows_per_proc
-    x_len = n + ghost
     flops = 2.0 * n * k
-    ell_bytes = n * k * (4 + value_bytes)
-    cells = []
-    for variant in ("flat", "blocked"):
-        if variant == "flat":
-            x_bytes = x_len * value_bytes
-            vmem = spmv_flat_vmem_bytes(
-                in_pad=n, ghost_pad=ghost, k_local=k, k_ghost=k,
-                value_bytes=value_bytes, rows=n, block_rows=block_rows,
-            )
-        else:
-            # x re-streamed once per row block (the cost of column blocking)
-            x_bytes = (n // block_rows) * (
-                -(-x_len // block_cols) * block_cols
-            ) * value_bytes
-            vmem = spmv_blocked_vmem_bytes(
-                bucket_k=k, value_bytes=value_bytes, rows=n,
-                block_rows=block_rows, block_cols=block_cols,
-            )
-        hbm = ell_bytes + x_bytes + n * value_bytes
-        t = max(hbm / V5E_HBM_BW, flops / V5E_VPU_FLOPS)
-        cells.append({
-            "variant": variant,
-            "hbm_bytes": hbm,
-            "flops": flops,
-            "intensity": flops / hbm,
-            "time_s": t,
-            "vmem_bytes": vmem,
-            "vmem_fits": vmem <= 16 * 2 ** 20,
-        })
-    return cells
+    hbm = n * k * (4 + value_bytes) + (n + ghost) * value_bytes \
+        + n * value_bytes
+    return {
+        "hbm_bytes": hbm,
+        "flops": flops,
+        "intensity": flops / hbm,
+        "time_s": max(hbm / V5E_HBM_BW, flops / V5E_VPU_FLOPS),
+    }
 
 
 def overlap_cell(
@@ -109,17 +76,14 @@ def overlap_cell(
 
 
 def kernel_rows():
-    out = []
-    for c in spmv_kernel_cells():
-        out.append((
-            f"roofline/spmv_{c['variant']}",
-            c["time_s"] * 1e6,
-            "kind=modeled-roofline"
-            f"|hbm_gb={c['hbm_bytes'] / 1e9:.3f}"
-            f"|intensity={c['intensity']:.4f}"
-            f"|vmem_kib={c['vmem_bytes'] / 2 ** 10:.1f}"
-            f"|vmem_fits={c['vmem_fits']}",
-        ))
+    c = spmv_kernel_cell()
+    out = [(
+        "roofline/spmv_flat",
+        c["time_s"] * 1e6,
+        "kind=modeled-roofline"
+        f"|hbm_gb={c['hbm_bytes'] / 1e9:.3f}"
+        f"|intensity={c['intensity']:.4f}",
+    )]
     ov = overlap_cell()
     out.append((
         "roofline/spmv_overlap",

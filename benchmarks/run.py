@@ -109,13 +109,12 @@ def measured_setup_exchange_rows(rows: int, tracer=None):
     return out
 
 
-def spmv_kernel_rows(rows: int, n_procs: int):
-    """Flat vs column-blocked SpMV kernel: deterministic modeled-VMEM
-    selection rows (per level + paper-scale fine level) and measured
-    CPU-reference / Pallas-interpret timings with equivalence asserted."""
-    from .spmv_kernel import measured_rows, selection_rows
+def spmv_kernel_rows(rows: int):
+    """ELL SpMV kernel: measured CPU-reference / Pallas-interpret timings
+    with equivalence asserted."""
+    from .spmv_kernel import measured_rows
 
-    return selection_rows(rows, n_procs) + measured_rows(rows)
+    return measured_rows(rows)
 
 
 def spmv_overlap_rows(rows: int, n_procs: int, tracer=None):
@@ -314,25 +313,19 @@ def verify_rows(rows: int):
     cache = PlanCache()
     out = []
 
-    for label, kwargs in (
-        ("hierarchy", {}),
-        ("hierarchy_blocked",
-         {"spmv_variant": "blocked", "spmv_block_cols": 64}),
-    ):
-        dh = DistributedHierarchy.setup(
-            build_hierarchy(A), mesh, procs_per_region=4, cache=cache,
-            **kwargs,
-        )
-        t0 = time.perf_counter()
-        counts = verify_hierarchy(dh)
-        dt = time.perf_counter() - t0
-        out.append((
-            f"verify/wall_seconds/{label}", dt * 1e6,
-            f"kind=measured-host|seconds={dt:.4f}"
-            f"|levels={counts.get('levels', 0)}"
-            f"|collectives={counts.get('collectives', 0)}"
-            f"|partitions={counts.get('partitions', 0)}",
-        ))
+    dh = DistributedHierarchy.setup(
+        build_hierarchy(A), mesh, procs_per_region=4, cache=cache,
+    )
+    t0 = time.perf_counter()
+    counts = verify_hierarchy(dh)
+    dt = time.perf_counter() - t0
+    out.append((
+        "verify/wall_seconds/hierarchy", dt * 1e6,
+        f"kind=measured-host|seconds={dt:.4f}"
+        f"|levels={counts.get('levels', 0)}"
+        f"|collectives={counts.get('collectives', 0)}"
+        f"|partitions={counts.get('partitions', 0)}",
+    ))
 
     cfg = reduced("mixtral-8x7b")
     moe_mesh = jax.make_mesh((1, n_procs), ("data", "model"))
@@ -484,7 +477,7 @@ def build_sections(rows: int, smoke: bool, tracer=None):
             ("amg", lambda: paper_figs.amg_solver_convergence(rows)),
             ("setup_exchange",
              lambda: setup_exchange_modeled(rows, SMOKE_PROCS)),
-            ("spmv_kernel", lambda: spmv_kernel_rows(rows, SMOKE_PROCS)),
+            ("spmv_kernel", lambda: spmv_kernel_rows(rows)),
             ("spmv_overlap",
              lambda: spmv_overlap_rows(rows, SMOKE_PROCS, tracer)),
             ("measured_exchange",
@@ -508,7 +501,7 @@ def build_sections(rows: int, smoke: bool, tracer=None):
         ("fig13", lambda: paper_figs.fig13_weak_scaling()),
         ("amg", paper_figs.amg_solver_convergence),
         ("setup_exchange", lambda: setup_exchange_modeled(rows, 256)),
-        ("spmv_kernel", lambda: spmv_kernel_rows(rows, 256)),
+        ("spmv_kernel", lambda: spmv_kernel_rows(rows)),
         ("spmv_overlap", lambda: spmv_overlap_rows(rows, 256, tracer)),
         ("measured_exchange",
          lambda: measured_exchange_rows(rows, tracer)),

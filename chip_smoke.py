@@ -119,11 +119,11 @@ def main(argv=None) -> int:
 
     spmv_impl = kernels.impl("spmv_ell")
     print(f"{'level':>5} {'rows':>9} {'nnz':>9} {'strategy':>9} "
-          f"{'spmv':>5} {'layout':>7} {'overlap':>7}  R/P")
+          f"{'spmv':>5} {'layout':>8} {'overlap':>7}  R/P")
     for lv, hl in zip(dh.levels, h.levels):
         rp = f"{lv.R.strategy}/{lv.P.strategy}" if lv.R else "-"
         print(f"{lv.index:>5} {lv.n:>9} {hl.A.nnz:>9} {lv.A.strategy:>9} "
-              f"{spmv_impl:>5} {lv.A.kernel_variant:>7} "
+              f"{spmv_impl:>5} {lv.A.local_layout:>8} "
               f"{lv.A.overlap_mode:>7}  {rp}")
 
     b = np.random.default_rng(SEED).normal(size=A.nrows)

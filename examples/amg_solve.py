@@ -39,10 +39,6 @@ def main():
                     "global operator")
     ap.add_argument("--no-device", action="store_true",
                     help="skip the device-resident solve")
-    ap.add_argument("--spmv-variant", default="auto",
-                    choices=["auto", "flat", "blocked"],
-                    help="per-level SpMV kernel layout (auto: modeled-VMEM "
-                    "selection; see also REPRO_SPMV_VMEM_LIMIT_BYTES)")
     args = ap.parse_args()
 
     import jax
@@ -108,23 +104,21 @@ def main():
         blocks, off = partition_fine_matrix(A, n_dev)
         dh = DistributedHierarchy.setup_partitioned(
             blocks, off, mesh, strategy=args.strategy, cache=cache,
-            spmv_variant=args.spmv_variant,
         )
         print(f"[device] setup {time.time() - t0:.1f}s")
         print(dh.setup_info.describe())
     else:
         dh = DistributedHierarchy.setup(
             h, mesh, strategy=args.strategy, cache=cache,
-            spmv_variant=args.spmv_variant,
         )
         print(f"[device] setup {time.time() - t0:.1f}s")
     print(dh.describe())
     for lvl, op, strat, rep in dh.selection_table():
         if op == "A" and rep:
             print(f"  L{lvl} {op}: {rep}")
-    for lvl, op, variant, ghost, ov, rep in dh.kernel_table():
+    for lvl, op, layout, ghost, ov, rep in dh.kernel_table():
         if op == "A" and rep:
-            print(f"  L{lvl} {op}: {rep}")
+            print(f"  L{lvl} {op}: local={layout} ghost={ghost} {rep}")
     if n_dev > 1:
         print("[device] measured per-level exchange (jitted executor):")
         for lvl, strat, secs in dh.measure_exchange_seconds():

@@ -36,6 +36,7 @@ Entry points: ``DistributedHierarchy.setup(...)``, ``.solve(b, x0=...)``,
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Callable, List, Optional, Tuple
 
 import numpy as np
@@ -47,15 +48,11 @@ from ..core.plan import Topology
 from ..core.selection import SelectionReport
 from ..obs import default_obs, now as _now
 from ..sparse.device import (
-    DEFAULT_BLOCK_COLS,
     DeviceEll,
-    DeviceEllBlocked,
-    KernelSelection,
     OverlapSelection,
     make_distributed_spmv,
     pack_vector,
     partitioned_to_device,
-    select_spmv_kernel,
     select_spmv_overlap,
     unpack_vector,
 )
@@ -83,16 +80,15 @@ _M_HALO = _OBS.counter(
 class DistOp:
     """One partitioned operator + its persistent collective + device form.
 
-    ``kernel`` records the flat-vs-blocked SpMV choice (and the flat
-    layout's diagonal-or-ELL local block) and ``overlap`` the
-    exchange/compute-overlap schedule choice, next to the plan's Section-5
-    transport choice, so all the selections travel with the operator.
+    ``overlap`` records the exchange/compute-overlap schedule choice next
+    to the plan's Section-5 transport choice, and the device form its
+    local and ghost layouts, so all the selections travel with the
+    operator.
     """
 
     part: PartitionedCSR
     coll: NeighborAlltoallV
-    ell: "DeviceEll | DeviceEllBlocked"
-    kernel: Optional[KernelSelection] = None
+    ell: DeviceEll
     overlap: Optional[OverlapSelection] = None
 
     @property
@@ -104,12 +100,9 @@ class DistOp:
         return self.coll.selection
 
     @property
-    def kernel_variant(self) -> str:
-        return self.kernel.variant if self.kernel else "flat"
-
-    @property
     def local_layout(self) -> str:
-        return self.kernel.local_layout if self.kernel else "ell"
+        """``diagonal`` or ``ell``: how the local block is stored."""
+        return "diagonal" if self.ell.offsets else "ell"
 
     @property
     def ghost_layout(self) -> str:
@@ -147,6 +140,21 @@ def _default_procs_per_region(n_procs: int) -> int:
     return 1
 
 
+def _make_op(part: PartitionedCSR, *, cache: PlanCache, topo: Topology,
+             strategy: str, value_bytes: int, params: MachineParams,
+             dtype, spmv_overlap: str) -> DistOp:
+    """A partitioned operator's :class:`DistOp`: its collective from the
+    plan cache, its device form, and the overlap choice against the
+    plan's modeled exchange time."""
+    coll = cache.collective(part.pattern, topo, strategy, value_bytes, params)
+    ell = partitioned_to_device(part, dtype)
+    osel = select_spmv_overlap(
+        part, plan_time(coll.plan, params),
+        mode=spmv_overlap, value_bytes=value_bytes,
+    )
+    return DistOp(part, coll, ell, osel)
+
+
 def _count_halo_values(dl: DistributedLevel) -> None:
     """``amg/halo_values`` of the level's A, R and P (obs on only)."""
     if not _OBS.enabled:
@@ -170,8 +178,6 @@ class DistributedHierarchy:
         strategy: str,
         params: MachineParams,
         value_bytes: int,
-        spmv_variant: str = "auto",
-        spmv_vmem_limit: Optional[int] = None,
         spmv_overlap: str = "auto",
         coarse_gather: str = "off",
     ):
@@ -186,9 +192,6 @@ class DistributedHierarchy:
         self.strategy = strategy
         self.params = params
         self.value_bytes = value_bytes
-        # the flat-vs-blocked kernel policy the hierarchy was built under
-        self.spmv_variant = spmv_variant
-        self.spmv_vmem_limit = spmv_vmem_limit
         # the exchange/compute-overlap policy (auto | on | off)
         self.spmv_overlap = spmv_overlap
         # coarsest-level dense allgatherv policy: "off" keeps the
@@ -224,9 +227,6 @@ class DistributedHierarchy:
         value_bytes: int = 8,
         cache: Optional[PlanCache] = None,
         dtype=np.float64,
-        spmv_variant: str = "auto",
-        spmv_vmem_limit: Optional[int] = None,
-        spmv_block_cols: int = DEFAULT_BLOCK_COLS,
         spmv_overlap: str = "auto",
         coarse_gather: str = "off",
         row_weights: Optional[np.ndarray] = None,
@@ -235,13 +235,10 @@ class DistributedHierarchy:
 
         ``strategy="auto"`` runs the paper's Section-5 selector per level
         and per transfer operator; pass a concrete strategy to pin it.
-        ``spmv_variant="auto"`` likewise selects the flat or column-blocked
-        SpMV kernel per operator from its modeled VMEM footprint against
-        ``spmv_vmem_limit`` (default: :func:`~repro.sparse.device.
-        default_spmv_vmem_limit`, env-overridable), and stores a flat
-        operator's local block by diagonals where its offsets show it banded
-        (a stencil fine level); ``"flat"``/``"blocked"`` pin the variant
-        with the ELL gather.  ``spmv_overlap="auto"`` selects the split
+        Each operator's local block is stored by diagonals where its offsets
+        show it banded (a stencil fine level), else as ELL
+        (:func:`~repro.sparse.device.partitioned_to_device`).
+        ``spmv_overlap="auto"`` selects the split
         exchange/compute-overlap schedule per operator whenever the modeled
         hidden exchange time beats the split overhead; ``"on"``/``"off"``
         pin it.  All choices are recorded on each :class:`DistOp`.
@@ -257,23 +254,10 @@ class DistributedHierarchy:
             n_procs, procs_per_region or _default_procs_per_region(n_procs)
         )
         cache = cache if cache is not None else default_plan_cache()
-
-        def make_op(mat, row_off, col_off) -> DistOp:
-            part = partition_rect_csr(mat, row_off, col_off)
-            coll = cache.collective(
-                part.pattern, topo, strategy, value_bytes, params
-            )
-            sel = select_spmv_kernel(
-                part, variant=spmv_variant,
-                vmem_limit_bytes=spmv_vmem_limit,
-                value_bytes=value_bytes, block_cols=spmv_block_cols,
-            )
-            ell = partitioned_to_device(part, sel, dtype, spmv_block_cols)
-            osel = select_spmv_overlap(
-                part, plan_time(coll.plan, params),
-                mode=spmv_overlap, value_bytes=value_bytes,
-            )
-            return DistOp(part, coll, ell, sel, osel)
+        make_op = partial(_make_op, cache=cache, topo=topo,
+                          strategy=strategy, value_bytes=value_bytes,
+                          params=params, dtype=dtype,
+                          spmv_overlap=spmv_overlap)
 
         if row_weights is None:
             offs = [block_offsets(lvl.A.nrows, n_procs) for lvl in h.levels]
@@ -294,7 +278,8 @@ class DistributedHierarchy:
             for k, lvl in enumerate(h.levels):
                 with _OBS.span("amg/build_level", level=k,
                                n=lvl.A.nrows) as lsp:
-                    A_op = make_op(lvl.A, offs[k], offs[k])
+                    A_op = make_op(
+                        partition_rect_csr(lvl.A, offs[k], offs[k]))
                     pad = int(np.diff(offs[k]).max())
                     dinv = inv_diag(lvl.A)
                     dl = DistributedLevel(
@@ -306,19 +291,18 @@ class DistributedHierarchy:
                         rho=lvl.rho or 1.0,
                     )
                     if lvl.P is not None and k + 1 < len(h.levels):
-                        dl.R = make_op(lvl.R, offs[k + 1], offs[k])
-                        dl.P = make_op(lvl.P, offs[k], offs[k + 1])
+                        dl.R = make_op(partition_rect_csr(
+                            lvl.R, offs[k + 1], offs[k]))
+                        dl.P = make_op(partition_rect_csr(
+                            lvl.P, offs[k], offs[k + 1]))
                     levels.append(dl)
                     _count_halo_values(dl)
                     lsp.set(strategy=A_op.strategy,
-                            kernel=A_op.kernel_variant,
                             layout=A_op.local_layout,
                             ghost=A_op.ghost_layout,
                             overlap=A_op.overlap_mode)
             dh = cls(levels, mesh, axis_name, topo, cache, dtype,
                      strategy, params, value_bytes,
-                     spmv_variant=spmv_variant,
-                     spmv_vmem_limit=spmv_vmem_limit,
                      spmv_overlap=spmv_overlap,
                      coarse_gather=coarse_gather)
         dh._host = h
@@ -341,9 +325,6 @@ class DistributedHierarchy:
         min_coarse: int = 64,
         strength_theta: float = 0.25,
         seed: int = 0,
-        spmv_variant: str = "auto",
-        spmv_vmem_limit: Optional[int] = None,
-        spmv_block_cols: int = DEFAULT_BLOCK_COLS,
         spmv_overlap: str = "auto",
         coarse_gather: str = "off",
     ) -> "DistributedHierarchy":
@@ -369,23 +350,10 @@ class DistributedHierarchy:
             strength_theta=strength_theta, seed=seed,
             strategy=strategy, value_bytes=value_bytes, params=params,
         )
-
-        def make_op(blocks, row_off, col_off) -> DistOp:
-            part = partitioned_from_blocks(blocks, row_off, col_off)
-            coll = cache.collective(
-                part.pattern, topo, strategy, value_bytes, params
-            )
-            sel = select_spmv_kernel(
-                part, variant=spmv_variant,
-                vmem_limit_bytes=spmv_vmem_limit,
-                value_bytes=value_bytes, block_cols=spmv_block_cols,
-            )
-            ell = partitioned_to_device(part, sel, dtype, spmv_block_cols)
-            osel = select_spmv_overlap(
-                part, plan_time(coll.plan, params),
-                mode=spmv_overlap, value_bytes=value_bytes,
-            )
-            return DistOp(part, coll, ell, sel, osel)
+        make_op = partial(_make_op, cache=cache, topo=topo,
+                          strategy=strategy, value_bytes=value_bytes,
+                          params=params, dtype=dtype,
+                          spmv_overlap=spmv_overlap)
 
         levels: List[DistributedLevel] = []
         with _OBS.span("amg/setup_partitioned", n_procs=n_procs,
@@ -393,8 +361,8 @@ class DistributedHierarchy:
             for k, sl in enumerate(setup.levels):
                 with _OBS.span("amg/build_level", level=k,
                                n=sl.nrows) as lsp:
-                    A_op = make_op(sl.A_blocks, sl.row_offsets,
-                                   sl.row_offsets)
+                    A_op = make_op(partitioned_from_blocks(
+                        sl.A_blocks, sl.row_offsets, sl.row_offsets))
                     pad = int(np.diff(sl.row_offsets).max())
                     dinv = np.zeros((n_procs, pad), dtype=dtype)
                     for p, Ab in enumerate(sl.A_blocks):
@@ -406,21 +374,18 @@ class DistributedHierarchy:
                         dinv=dinv, rho=sl.rho or 1.0,
                     )
                     if sl.P_blocks is not None and k + 1 < len(setup.levels):
-                        dl.R = make_op(sl.R_blocks, sl.coarse_offsets,
-                                       sl.row_offsets)
-                        dl.P = make_op(sl.P_blocks, sl.row_offsets,
-                                       sl.coarse_offsets)
+                        dl.R = make_op(partitioned_from_blocks(
+                            sl.R_blocks, sl.coarse_offsets, sl.row_offsets))
+                        dl.P = make_op(partitioned_from_blocks(
+                            sl.P_blocks, sl.row_offsets, sl.coarse_offsets))
                     levels.append(dl)
                     _count_halo_values(dl)
                     lsp.set(strategy=A_op.strategy,
-                            kernel=A_op.kernel_variant,
                             layout=A_op.local_layout,
                             ghost=A_op.ghost_layout,
                             overlap=A_op.overlap_mode)
             dh = cls(levels, mesh, axis_name, topo, cache, dtype,
                      strategy, params, value_bytes,
-                     spmv_variant=spmv_variant,
-                     spmv_vmem_limit=spmv_vmem_limit,
                      spmv_overlap=spmv_overlap,
                      coarse_gather=coarse_gather)
         dh.setup_info = setup
@@ -773,8 +738,6 @@ class DistributedHierarchy:
                 value_bytes=self.value_bytes,
                 cache=self.cache,
                 dtype=self.dtype,
-                spmv_variant=self.spmv_variant,
-                spmv_vmem_limit=self.spmv_vmem_limit,
                 spmv_overlap=self.spmv_overlap,
                 coarse_gather=self.coarse_gather,
                 row_weights=row_weights,
@@ -803,20 +766,18 @@ class DistributedHierarchy:
     def kernel_table(
         self,
     ) -> List[Tuple[int, str, str, str, str, Optional[str]]]:
-        """[(level, op, kernel variant, ghost layout, overlap mode,
-        selection report)] — the flat-vs-blocked SpMV choice, the ghost
-        block's form (``compact``, ``rows`` or ``none``) and the
+        """[(level, op, local layout, ghost layout, overlap mode, overlap
+        report)] — the local block's form (``diagonal`` or ``ell``), the
+        ghost block's (``compact``, ``rows`` or ``none``) and the
         exchange/compute-overlap choice per operator, mirroring
-        :meth:`selection_table` for the transport choice; the report names
-        the local layout (``local=ell`` or ``local=diagonal(D=...)``)."""
+        :meth:`selection_table` for the transport choice."""
         rows = []
         for lv in self.levels:
             for name, op in (("A", lv.A), ("R", lv.R), ("P", lv.P)):
                 if op is None:
                     continue
-                reps = [str(s) for s in (op.kernel, op.overlap) if s]
-                rep = "; ".join(reps) if reps else None
-                rows.append((lv.index, name, op.kernel_variant,
+                rep = str(op.overlap) if op.overlap else None
+                rows.append((lv.index, name, op.local_layout,
                              op.ghost_layout, op.overlap_mode, rep))
         return rows
 
@@ -830,7 +791,7 @@ class DistributedHierarchy:
             t = lv.A.coll.plan.stats.totals()
             lines.append(
                 f"  L{lv.index}: n={lv.n:>8,d} pad={lv.pad:>6d} "
-                f"A={lv.A.strategy:8s} kern={lv.A.kernel_variant:7s} "
+                f"A={lv.A.strategy:8s} "
                 f"local={lv.A.local_layout:8s} "
                 f"ghost={lv.A.ghost_layout:7s} "
                 f"ov={lv.A.overlap_mode:4s} "

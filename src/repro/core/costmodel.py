@@ -133,7 +133,7 @@ def init_time(plan: CommPlan, params: MachineParams,
 # Exchange/compute overlap terms.
 #
 # The split SpMV schedule (sparse.device.make_distributed_spmv(overlap=True))
-# runs the local-bucket matvec while the NeighborAlltoallV is in flight, so
+# runs the local-block matvec while the NeighborAlltoallV is in flight, so
 # of a modeled exchange time tx only max(0, tx - tl) stays exposed, where tl
 # is the local compute time.  The compute side is the same roofline
 # arithmetic as benchmarks/roofline_report.py (which imports these

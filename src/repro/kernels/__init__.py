@@ -20,8 +20,7 @@ from typing import Optional
 #: op -> platform -> implementation: ``"pallas"`` (the Pallas TPU kernel)
 #: or ``"xla"`` (the op's jnp body in ``ref.py``, compiled by XLA).
 IMPLS = {
-    # Mosaic refuses the VMEM gather x[cols, 0] of every SpMV body, and the
-    # (256, K) blocks of the bucketed layout break the (8, 128) tiling.
+    # Mosaic refuses the VMEM gather x[cols, 0] of the SpMV body.
     "spmv_ell": {"tpu": "xla", "cpu": "xla"},
     # Mosaic's gather lowering refuses the row gather of gather_rows and
     # combine_rows ("Shape mismatch in input, indices and output").

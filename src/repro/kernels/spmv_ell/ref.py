@@ -1,5 +1,4 @@
-"""Pure-jnp bodies for ELL SpMV (flat and column-blocked layouts) and the
-diagonal SpMV of a banded block."""
+"""Pure-jnp bodies for ELL SpMV and the diagonal SpMV of a banded block."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -39,43 +38,3 @@ def spmv_dia(offsets: tuple, vals: jnp.ndarray, x: jnp.ndarray):
         o = m + offsets[d]
         y = y + vals[d] * xp[o: o + R]
     return y
-
-
-def spmv_ell_blocked_ref(
-    cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray, block_cols: int
-):
-    """Column-bucketed layout: cols/vals [R, C*K] with bucket ``j`` in
-    columns [j*K, (j+1)*K) holding in-bucket indices into
-    x[j*block_cols:(j+1)*block_cols]; x: [C*block_cols] -> y [R].
-
-    Same arithmetic as the blocked Pallas kernel, expressed as one flat
-    gather with the bucket base added back.
-    """
-    C = x.shape[0] // int(block_cols)
-    K = cols.shape[1] // C
-    base = jnp.repeat(
-        jnp.arange(C, dtype=cols.dtype) * jnp.asarray(block_cols, cols.dtype),
-        K,
-    )
-    return jnp.sum(vals.T * x[(cols + base[None, :]).T], axis=0)
-
-
-def spmv_ell_blocked_partial_ref(
-    cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray, y0: jnp.ndarray,
-    bucket_lo: int, bucket_hi: int, block_cols: int, n_buckets: int,
-):
-    """Oracle for :func:`spmv_ell_blocked_partial`: accumulate buckets
-    [lo, hi) of the full [R, C*K] layout into a carried ``y0``.  ``x``
-    covers exactly that range ((hi-lo) * block_cols entries)."""
-    lo, hi = int(bucket_lo), int(bucket_hi)
-    if hi <= lo:
-        return y0
-    K = cols.shape[1] // int(n_buckets)
-    sl_cols = cols[:, lo * K: hi * K]
-    sl_vals = vals[:, lo * K: hi * K]
-    base = jnp.repeat(
-        jnp.arange(hi - lo, dtype=cols.dtype)
-        * jnp.asarray(block_cols, cols.dtype),
-        K,
-    )
-    return y0 + jnp.sum(sl_vals.T * x[(sl_cols + base[None, :]).T], axis=0)

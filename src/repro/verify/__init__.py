@@ -5,13 +5,12 @@ package checks the whole pattern.  Three passes:
 
 * :mod:`.invariants` — host-side structural checks over patterns, plans,
   partitions, device ELL layouts and MoE dispatch geometry (conservation,
-  duality, round conflict-freedom, bucket exhaustiveness).
+  duality, round conflict-freedom).
 * :mod:`.jaxpr_audit` — trace bound executors and prove the compiled
   collective sequence matches the frozen DevicePlan (SPMD uniformity; no
   collective under data-dependent control flow).
-* :mod:`.kernel_budget` — the Pallas kernels' actual BlockSpec footprints
-  agree with the modeled VMEM estimators, and bucket-skip maps cover every
-  nonzero exactly once.
+* :mod:`.kernel_budget` — the SpMV kernel's BlockSpec footprint fits one
+  core's VMEM.
 
 Entry points: :func:`verify_hierarchy` sweeps every operator of a
 ``DistributedHierarchy``; ``ServeEngine.verify()`` checks a serving
@@ -19,7 +18,7 @@ engine's MoE plans; ``PlanCache`` calls :func:`verify_cache_value` /
 :func:`audit_executor` on insertion when :func:`verify_enabled` — i.e.
 ``REPRO_VERIFY=1`` (tests/CI default via ``test.sh``; unset in production
 hot paths).  All failures raise :class:`VerifyError` with a diagnostic
-naming the offending rank / slot / bucket.
+naming the offending rank / slot.
 """
 from __future__ import annotations
 
@@ -33,7 +32,6 @@ from .invariants import (
     verify_dense_plan,
     verify_device_ell,
     verify_device_plan,
-    verify_ell_blocked,
     verify_moe_dispatch,
     verify_moe_plan,
     verify_partition,
@@ -50,11 +48,9 @@ from .jaxpr_audit import (
     trace_collectives,
 )
 from .kernel_budget import (
-    blocked_kernel_actual_bytes,
-    check_bucket_map,
     flat_kernel_actual_bytes,
-    verify_bucket_map,
     verify_kernel_budget,
+    vmem_bytes_per_core,
 )
 
 __all__ = [
@@ -67,7 +63,6 @@ __all__ = [
     "verify_collective",
     "verify_partition",
     "verify_device_ell",
-    "verify_ell_blocked",
     "verify_moe_plan",
     "verify_moe_dispatch",
     "verify_dense_plan",
@@ -79,10 +74,8 @@ __all__ = [
     "audit_executor",
     "audit_dense_executor",
     "flat_kernel_actual_bytes",
-    "blocked_kernel_actual_bytes",
     "verify_kernel_budget",
-    "check_bucket_map",
-    "verify_bucket_map",
+    "vmem_bytes_per_core",
     "verify_dist_op",
     "verify_hierarchy",
 ]
@@ -101,9 +94,7 @@ def verify_enabled() -> bool:
 
 def verify_dist_op(op, *, value_bytes: int = 8) -> Dict[str, int]:
     """All static checks for one distributed operator (a ``DistOp``):
-    partition, bound collective, device layout, kernel budget, and — for
-    blocked layouts — bucket-map exhaustiveness over the full window and
-    both overlap windows (local / ghost) when an exchange exists.
+    partition, bound collective, device layout and kernel budget.
 
     Each pass runs under an obs span (``verify/<pass>``) so
     ``obs.report()`` breaks verification wall time out per pass.
@@ -123,21 +114,11 @@ def verify_dist_op(op, *, value_bytes: int = 8) -> Dict[str, int]:
         with obs.span("verify/collective"):
             verify_collective(op.coll)
         tick("collectives")
-    ell = op.ell
-    if hasattr(ell, "bucket_K"):
-        with obs.span("verify/blocked_layout"):
-            verify_ell_blocked(ell, op.part)
-            verify_bucket_map(ell)
-            if op.coll is not None and ell.n_ghost_buckets:
-                verify_bucket_map(ell, bucket_hi=ell.n_local_buckets)
-                verify_bucket_map(ell, bucket_lo=ell.n_local_buckets)
-        tick("blocked_layouts")
-    else:
-        with obs.span("verify/flat_layout"):
-            verify_device_ell(ell, op.part)
-        tick("flat_layouts")
+    with obs.span("verify/layout"):
+        verify_device_ell(op.ell, op.part)
+    tick("layouts")
     with obs.span("verify/kernel_budget"):
-        verify_kernel_budget(ell, op.kernel, value_bytes=value_bytes)
+        verify_kernel_budget(op.ell, value_bytes=value_bytes)
     tick("kernel_budgets")
     return counts
 

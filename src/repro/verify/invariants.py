@@ -4,7 +4,7 @@ The paper's persistent neighborhood collectives hand the planner the *whole*
 communication pattern, which makes whole-pattern checking possible: every
 invariant the planners rely on implicitly is stated here as an explicit,
 machine-checked predicate.  A violated invariant raises :class:`VerifyError`
-with a diagnostic naming the offending rank / slot / bucket, instead of
+with a diagnostic naming the offending rank / slot, instead of
 manifesting downstream as a hang (a ppermute round with a doubly-booked
 rank) or a silently wrong residual (a dropped or duplicated ghost value).
 
@@ -25,9 +25,9 @@ What each check proves:
 * :func:`verify_partition` — every ghost column of a :class:`PartitionedCSR`
   is served by exactly one exchange slot (``needs[p][j]``), and the
   attached pattern agrees with the column ownership.
-* :func:`verify_device_ell` / :func:`verify_ell_blocked` — the device ELL
-  forms carry exactly the partition's nonzeros: each nonzero lands in
-  exactly one (row, column / bucket) slot and all padding is inert.
+* :func:`verify_device_ell` — the device form carries exactly the
+  partition's nonzeros: each nonzero lands in exactly one (row, column)
+  or (diagonal, row) slot and all padding is inert.
 * :func:`verify_collective` — plan checks plus the frozen device plan
   (round perms, index-array shapes and sentinel bounds).
 * :func:`verify_moe_plan` / :func:`verify_moe_dispatch` — dispatch geometry
@@ -55,7 +55,7 @@ from ..core.plan import (
 class VerifyError(Exception):
     """A violated plan/kernel invariant.
 
-    ``context`` carries the structured fields (rank, slot, bucket, ...)
+    ``context`` carries the structured fields (rank, slot, ...)
     the message interpolates, so programmatic consumers need not parse
     the string.
     """
@@ -323,7 +323,7 @@ def verify_collective(coll) -> None:
 
 
 # ---------------------------------------------------------------------------
-# partitions + device ELL forms (bucket exhaustiveness)
+# partitions + device ELL forms
 # ---------------------------------------------------------------------------
 
 
@@ -429,13 +429,13 @@ def _multiset_equal(where: str, p: int, keys_a, vals_a, keys_b, vals_b,
 
 
 def verify_device_ell(ell, part) -> None:
-    """Flat ELL carries exactly the partition's nonzeros, once each, with
-    padding entries pointing at the sentinel x slot with value zero; a local
-    block stored by diagonals carries them at ``row + offsets[d]``; a
+    """The device ELL carries exactly the partition's nonzeros, once each,
+    with padding entries pointing at the sentinel x slot with value zero; a
+    local block stored by diagonals carries them at ``row + offsets[d]``; a
     compact ghost block's ``ghost_rows`` are ascending, unique, in range and
     omit no row that holds a ghost entry."""
     if ell.row_pad != int(np.diff(part.offsets).max()):
-        _fail("flat ELL row padding disagrees with the partition",
+        _fail("ELL row padding disagrees with the partition",
               row_pad=ell.row_pad)
     ghost_rows = ell.ghost_rows
     if ghost_rows is None:      # row-aligned: every padded row
@@ -459,16 +459,16 @@ def verify_device_ell(ell, part) -> None:
             live = vals != 0
             if np.any(cols[live] >= blk.ncols):
                 r = int(np.argwhere(live & (cols >= blk.ncols))[0][0])
-                _fail(f"flat ELL {what} entry references a column outside "
+                _fail(f"ELL {what} entry references a column outside "
                       "the block", rank=p, row=int(rows[r]))
             if np.any(cols > width):
-                _fail(f"flat ELL {what} column index beyond the sentinel",
+                _fail(f"ELL {what} column index beyond the sentinel",
                       rank=p, sentinel=width)
             r_idx, c_idx = np.nonzero(live)
             keys = np.stack([rows[r_idx].astype(np.int64),
                              cols[live].astype(np.int64)], 1)
             ck, cv = _csr_triples(blk)
-            _multiset_equal(f"flat ELL {what} block", p, keys, vals[live],
+            _multiset_equal(f"ELL {what} block", p, keys, vals[live],
                             ck, cv, "ell_nnz", "csr_nnz")
 
 
@@ -509,64 +509,6 @@ def _verify_diagonal_block(blk, offsets, vals, p: int) -> None:
     ck, cv = _csr_triples(blk)
     _multiset_equal("diagonal local block", p, keys, vals[live], ck, cv,
                     "dia_nnz", "csr_nnz")
-
-
-def verify_ell_blocked(ell, part) -> None:
-    """Every nonzero of the partition lands in exactly one ELL bucket slot
-    (local buckets for local columns, trailing ghost buckets for exchange
-    slots) and ``bucket_K`` bounds hold."""
-    bc = ell.block_cols
-    Cl, C, K = ell.n_local_buckets, ell.n_buckets, ell.K
-    if ell.cols.shape != (ell.n_procs, ell.row_pad, C * K):
-        _fail("blocked ELL arrays have the wrong shape",
-              shape=ell.cols.shape)
-    if int(ell.bucket_K.max(initial=0)) > K:
-        _fail("bucket_K exceeds the uniform padded width",
-              bucket=int(np.argmax(ell.bucket_K)), K=K)
-    for p in range(part.n_procs):
-        vals = ell.vals[p].reshape(ell.row_pad, C, K)
-        cols = ell.cols[p].reshape(ell.row_pad, C, K)
-        live = vals != 0
-        if np.any(cols[live] >= bc) or np.any(cols[live] < 0):
-            _fail("blocked ELL in-bucket index outside the bucket",
-                  rank=p, block_cols=bc)
-        r_idx, b_idx, k_idx = np.nonzero(live)
-        # device-side nonzeros as (row, absolute x position)
-        keys = np.stack(
-            [r_idx.astype(np.int64),
-             b_idx.astype(np.int64) * bc + cols[live].astype(np.int64)], 1)
-        # per-bucket occupancy must respect the recorded bucket_K
-        if len(r_idx):
-            occ = np.bincount(r_idx * C + b_idx,
-                              minlength=ell.row_pad * C)
-            occ = occ.reshape(ell.row_pad, C).max(0)
-            over = np.flatnonzero(occ > ell.bucket_K)
-            if len(over):
-                _fail("bucket holds more nonzeros than bucket_K records",
-                      rank=p, bucket=int(over[0]), count=int(occ[over[0]]),
-                      bucket_K=int(ell.bucket_K[over[0]]))
-        # partition-side nonzeros in the same coordinates
-        lk, lv = _csr_triples(part.local[p])
-        gk, gv = _csr_triples(part.ghost[p])
-        want_keys = np.concatenate([
-            np.stack([lk[:, 0],
-                      (lk[:, 1] // bc) * bc + lk[:, 1] % bc], 1)
-            if len(lk) else np.zeros((0, 2), np.int64),
-            np.stack([gk[:, 0],
-                      (Cl + gk[:, 1] // bc) * bc + gk[:, 1] % bc], 1)
-            if len(gk) else np.zeros((0, 2), np.int64),
-        ])
-        want_vals = np.concatenate([lv, gv])
-        # local nonzeros must stay in local buckets, ghosts in ghost buckets
-        bucket_of = keys[:, 1] // bc
-        dev_is_ghost = bucket_of >= Cl
-        n_ghost_dev = int(dev_is_ghost.sum())
-        if n_ghost_dev != len(gv):
-            _fail("blocked ELL ghost-bucket population disagrees with the "
-                  "ghost block (duplicated or dropped bucket entries)",
-                  rank=p, ell_ghost_nnz=n_ghost_dev, csr_ghost_nnz=len(gv))
-        _multiset_equal("blocked ELL", p, keys, vals[live], want_keys,
-                        want_vals, "ell_nnz", "csr_nnz")
 
 
 # ---------------------------------------------------------------------------

@@ -54,10 +54,8 @@ def main():
     # (4) fine-level device SpMV vs host oracle
     part = partition_csr(h.levels[0].A, 8)
     coll = cache.collective(part.pattern, Topology(8, 4), "auto")
-    for variant in ("flat", "auto"):
-        y_dev = distributed_spmv(part, coll, mesh, "proc", b, variant=variant)
-        np.testing.assert_allclose(y_dev, A.matvec(b), rtol=1e-12,
-                                   atol=1e-12)
+    y_dev = distributed_spmv(part, coll, mesh, "proc", b)
+    np.testing.assert_allclose(y_dev, A.matvec(b), rtol=1e-12, atol=1e-12)
     layouts = [lv.A.local_layout for lv in dh.levels]
     assert layouts == ["diagonal"] + ["ell"] * (len(layouts) - 1), layouts
     # the fine A's ghost entries sit on its strips' boundary rows alone

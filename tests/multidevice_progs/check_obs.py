@@ -202,7 +202,8 @@ def check_amg_span_tree():
     assert all(e.depth == 1 for e in by_name["amg/build_level"])
     # build-level spans carry the per-level selection verdicts
     for e in by_name["amg/build_level"]:
-        assert {"level", "strategy", "kernel", "overlap"} <= set(e.attrs)
+        assert {"level", "strategy", "layout", "ghost", "overlap"} \
+            <= set(e.attrs)
     (solve,) = by_name["amg/solve"]
     assert solve.depth == 0 and solve.attrs["iters"] == len(hist)
     iters = by_name["amg/vcycle_iter"]

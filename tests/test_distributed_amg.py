@@ -42,25 +42,14 @@ def test_distributed_amg_vcycle_matches_host():
     assert "A=full" in out or "A=partial" in out
 
 
-def test_blocked_spmv_hierarchy_matches_host():
-    """Column-blocked kernel end to end: forced-blocked and auto-selected
-    (fine blocked / coarse flat) hierarchies both track the host solver."""
-    out = run_prog("check_blocked_spmv.py")
-    assert "ALL_OK" in out
-    assert "forced-blocked residual history OK" in out
-    assert "auto mixed-variant residual history OK" in out
-    assert "kern=blocked" in out and "kern=flat" in out
-
-
 def test_overlap_spmv_hierarchy_matches_host():
     """Exchange/compute-overlapped schedule end to end: forced-overlap
-    hierarchies (flat + blocked kernels, and the auto selection with the
-    fine level's diagonal local block) track the host solver, auto
-    records its per-level decision, visible in describe()."""
+    hierarchies (the fine level's local block by diagonals, and by its ELL
+    gather) track the host solver, auto records its per-level decision,
+    visible in describe()."""
     out = run_prog("check_overlap_spmv.py")
     assert "ALL_OK" in out
-    assert "forced-overlap flat residual history OK" in out
-    assert "forced-overlap blocked residual history OK" in out
-    assert "forced-overlap auto residual history OK" in out
+    assert "forced-overlap diagonal residual history OK" in out
+    assert "forced-overlap ell residual history OK" in out
     assert "auto-overlap residual history OK" in out
     assert "ov=off" in out

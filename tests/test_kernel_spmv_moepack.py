@@ -1,5 +1,6 @@
-"""ELL SpMV (flat + column-blocked) + MoE pack/combine kernels vs oracles
-(+ AMG matrices)."""
+"""ELL and diagonal SpMV + MoE pack/combine kernels vs oracles (+ AMG
+matrices)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,12 +8,8 @@ import pytest
 from repro.amg import diffusion_2d
 from repro.kernels.moe_pack import combine_rows_ref, gather_rows_ref
 from repro.kernels.moe_pack.moe_pack import combine_rows, gather_rows
-from repro.kernels.spmv_ell import (
-    csr_to_ell,
-    spmv_ell_blocked_ref,
-    spmv_ell_ref,
-)
-from repro.kernels.spmv_ell.spmv_ell import spmv_ell, spmv_ell_blocked
+from repro.kernels.spmv_ell import csr_to_ell, spmv_dia, spmv_ell_ref
+from repro.kernels.spmv_ell.spmv_ell import spmv_ell
 
 
 @pytest.mark.parametrize("R,K,N,br", [(64, 4, 32, 16), (128, 7, 100, 32),
@@ -47,59 +44,71 @@ def test_spmv_prime_rows_padded(R, br):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("R,C,K,bc,br", [(64, 3, 4, 16, 16),
-                                         (97, 5, 3, 32, 32),   # prime R
-                                         (128, 1, 6, 64, 32)])  # single bucket
-def test_spmv_blocked_random(R, C, K, bc, br):
-    """Blocked kernel vs its oracle on random bucketed layouts."""
-    rng = np.random.default_rng(5)
-    cols = rng.integers(0, bc, size=(R, C * K)).astype(np.int32)
-    vals = rng.normal(size=(R, C * K)).astype(np.float32)
-    x = rng.normal(size=C * bc).astype(np.float32)
-    want = spmv_ell_blocked_ref(
-        jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x), bc
-    )
-    got = spmv_ell_blocked(
-        jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x),
-        block_cols=bc, block_rows=br, interpret=True,
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+@pytest.mark.parametrize("R,K,N", [(64, 12, 48), (97, 15, 160),
+                                   (128, 6, 64)])
+def test_spmv_ell_ref_matches_dense(R, K, N):
+    """The jnp ELL body against the dense product: padding entries point
+    at the sentinel x slot ``N`` (holding 0.0) with nonzero values, some
+    rows are all padding, and a column repeats within a row (its products
+    add)."""
+    rng = np.random.default_rng(R)
+    cols = rng.integers(0, N, size=(R, K)).astype(np.int32)
+    vals = rng.normal(size=(R, K)).astype(np.float32)
+    lens = rng.integers(0, K + 1, size=R)
+    lens[:3] = 0                                  # all-padding rows
+    cols[:, K - 1] = np.where(lens == K, cols[:, 0], cols[:, K - 1])
+    cols[np.arange(K)[None, :] >= lens[:, None]] = N
+    assert np.any(lens == K)                      # a repeated column
+    x = np.concatenate([rng.normal(size=N), [0.0]]).astype(np.float32)
+    dense = np.zeros((R, N))
+    for i in range(R):
+        for k in range(lens[i]):
+            dense[i, cols[i, k]] += vals[i, k]
+    got = spmv_ell_ref(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x))
+    np.testing.assert_allclose(np.asarray(got), dense @ x[:N],
                                rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got)[:3], 0.0)
 
 
-def test_spmv_blocked_matches_flat_on_amg_matrix():
-    """Column-bucketed packing + blocked kernel == flat kernel == host
-    matvec on a real AMG operator."""
-    from repro.sparse import (
-        partition_csr,
-        partitioned_to_ell,
-        partitioned_to_ell_blocked,
-    )
+def _offset_set(kind: str, R: int, rng) -> tuple:
+    if kind == "zero":
+        return (0,)
+    if kind == "one_sided":
+        return tuple(sorted({0, *rng.integers(1, R + 1, size=3).tolist()}))
+    if kind == "symmetric":
+        o = rng.integers(1, R + 1, size=2).tolist()
+        return tuple(sorted({0, *o, *(-v for v in o)}))
+    # beyond the row count on both sides
+    return tuple(sorted({-R - int(rng.integers(0, 3)), -1, 0,
+                         R + int(rng.integers(0, 3))}))
 
-    A = diffusion_2d(16, 16)
-    part = partition_csr(A, 1)          # single block: no ghosts
-    ell = partitioned_to_ell(part, dtype=np.float32)
-    bell = partitioned_to_ell_blocked(part, block_cols=64, dtype=np.float32)
-    rng = np.random.default_rng(6)
-    x = rng.normal(size=A.ncols).astype(np.float32)
 
-    xf = jnp.asarray(np.concatenate([x, [0.0]]).astype(np.float32))
-    flat = spmv_ell(jnp.asarray(ell.local_cols[0]),
-                    jnp.asarray(ell.local_vals[0]), xf,
-                    block_rows=64, interpret=True)
-    xb = np.zeros(bell.x_len, dtype=np.float32)
-    xb[: A.ncols] = x
-    blocked = spmv_ell_blocked(
-        jnp.asarray(bell.cols[0]), jnp.asarray(bell.vals[0]),
-        jnp.asarray(xb), block_cols=bell.block_cols, block_rows=64,
-        interpret=True,
-    )
-    want = A.matvec(x.astype(np.float64))
-    np.testing.assert_allclose(np.asarray(flat)[: A.nrows], want,
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(blocked)[: A.nrows],
-                               np.asarray(flat)[: A.nrows],
-                               rtol=1e-5, atol=1e-6)
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("R", [1, 7, 64, 257])
+@pytest.mark.parametrize("kind", ["one_sided", "symmetric", "beyond",
+                                  "zero"])
+def test_spmv_dia_matches_dense(kind, R, dtype):
+    """The diagonal SpMV against the dense product of the same diagonals
+    (entries whose column falls outside x hold 0.0, as the layout stores
+    them)."""
+    rng = np.random.default_rng(R + len(kind))
+    offsets = _offset_set(kind, R, rng)
+    vals = rng.normal(size=(len(offsets), R))
+    dense = np.zeros((R, R))
+    for d, o in enumerate(offsets):
+        i = np.arange(R)
+        inside = (i + o >= 0) & (i + o < R)
+        vals[d, ~inside] = 0.0
+        dense[i[inside], i[inside] + o] = vals[d, inside]
+    x = rng.normal(size=R)
+    with jax.enable_x64(True):
+        got = np.asarray(spmv_dia(offsets, jnp.asarray(vals.astype(dtype)),
+                                  jnp.asarray(x.astype(dtype))))
+    assert got.dtype == dtype
+    want = dense.astype(dtype).astype(np.float64) @ \
+        x.astype(dtype).astype(np.float64)
+    tol = 1e-5 if dtype == np.float32 else 1e-13
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 
 def test_spmv_amg_matrix():

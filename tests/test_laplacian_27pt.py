@@ -110,8 +110,8 @@ def test_four_device_solve_matches_host(host_27pt):
         _, hist = dh.solve(b, tol=1e-8, max_iters=60)
     fine = dh.levels[0].A
     assert fine.local_layout == "diagonal"
-    assert len(fine.kernel.offsets) == 27
-    assert max(abs(o) for o in fine.kernel.offsets) == 8 * 8 + 8 + 1
+    assert len(fine.ell.offsets) == 27
+    assert max(abs(o) for o in fine.ell.offsets) == 8 * 8 + 8 + 1
     assert [len(fine.part.pattern.sends_for(q)) for q in range(4)] == [3] * 4
     _, hist_host = solve(host_27pt, b, tol=1e-8, max_iters=60)
     assert len(hist) == len(hist_host) and hist[-1] < 1e-8
@@ -119,26 +119,19 @@ def test_four_device_solve_matches_host(host_27pt):
 
 
 def ghost_slots_stored(ell) -> int:
-    if not ell.ghost_pad:
-        return 0
-    if hasattr(ell, "ghost_cols"):
-        return ell.ghost_cols.size
-    return ell.n_procs * ell.row_pad * ell.n_ghost_buckets * ell.K
+    return ell.ghost_cols.size if ell.ghost_pad else 0
 
 
-@pytest.mark.parametrize("variant", ["auto", "blocked"])
-def test_ghost_slot_and_halo_value_counters(host_27pt, default_on, variant):
+def test_ghost_slot_and_halo_value_counters(host_27pt, default_on):
     """``sparse/ghost_slots`` counts the ghost entries and the slots the
-    flat, diagonal or column-blocked layout stores for them;
+    compact or row-aligned ghost blocks store for them;
     ``amg/halo_values`` each operator's plan values per application."""
     mesh = jax.make_mesh((4,), ("proc",), devices=jax.devices()[:4])
     dh = DistributedHierarchy.setup(host_27pt, mesh, dtype=np.float32,
-                                    value_bytes=4, spmv_variant=variant)
+                                    value_bytes=4)
     ops = [(lv.index, name, op) for lv in dh.levels
            for name, op in (("A", lv.A), ("R", lv.R), ("P", lv.P))
            if op is not None]
-    assert {op.kernel_variant for _, _, op in ops} == (
-        {"flat"} if variant == "auto" else {"blocked"})
     slots = default_on.counter("sparse/ghost_slots")
     entries = sum(m.nnz for _, _, op in ops for m in op.part.ghost)
     stored = sum(ghost_slots_stored(op.ell) for _, _, op in ops)
@@ -155,21 +148,17 @@ def test_ghost_slot_and_halo_value_counters(host_27pt, default_on, variant):
     assert rows.value(kind="stored") == sum(
         op.ell.ghost_rows.size for op in compact)
     fine = dh.levels[0].A
-    if variant == "auto":
-        # the diagonal fine A and the ELL L0 R and P compact; each stores
-        # its ghost rows' slots alone
-        assert fine.local_layout == "diagonal"
-        assert {(k, name) for k, name, op in ops
-                if op.ghost_layout == "compact"} >= {
-            (0, "A"), (0, "R"), (0, "P")}
-        assert fine.ell.ghost_rows.shape == (4, 120)
-        assert fine.ell.ghost_cols.shape[:2] == (4, 120)
-        assert slots.value(kind="stored") < sum(
-            4 * op.ell.row_pad * op.ell.ghost_cols.shape[2]
-            for op in ghosted)
-    else:
-        assert not compact and rows.value(kind="stored") == 0
-        assert {op.ghost_layout for op in ghosted} == {"rows"}
+    # the diagonal fine A and the ELL L0 R and P compact; each stores its
+    # ghost rows' slots alone
+    assert fine.local_layout == "diagonal"
+    assert {(k, name) for k, name, op in ops
+            if op.ghost_layout == "compact"} >= {
+        (0, "A"), (0, "R"), (0, "P")}
+    assert fine.ell.ghost_rows.shape == (4, 120)
+    assert fine.ell.ghost_cols.shape[:2] == (4, 120)
+    assert slots.value(kind="stored") < sum(
+        4 * op.ell.row_pad * op.ell.ghost_cols.shape[2]
+        for op in ghosted)
     halo = default_on.counter("amg/halo_values")
     for k, name, op in ops:
         # every ghost value arrives once, some relayed through a region

@@ -12,18 +12,17 @@ Invariants under test:
   P6  The cost model is monotone in message sizes.
   P7  MoE capacity packing: slots are unique, within bounds, and respect
       per-expert capacity.
-  P8  Column-bucketed ELL packing + the blocked SpMV kernel agree with the
-      flat kernel, the jnp oracle, and the host matvec on ANY random
-      sparsity/ghost pattern.
-  P9  The overlap schedule's split execution (local buckets, then ghost
-      buckets carried on top — via both the partial and the bucket-skipping
-      kernel) equals the one-shot blocked kernel and the host matvec on ANY
-      random sparsity/ghost pattern.
+  P8  ELL packing (Pallas kernel and jnp oracle) and diagonal packing of
+      the local block agree with each other and with the host matvec on
+      ANY random sparsity/ghost pattern.
+  P9  The overlap schedule's split execution (local block while the
+      exchange runs, then the ghost block added on top) equals the fused
+      schedule and the host matvec on ANY random sparsity/ghost pattern.
   P10 The static verifier (repro.verify) accepts every plan/partition/
       layout built from random patterns, and rejects every injected
-      corruption — size-mismatched send, dropped ghost column, duplicated
-      bucket, round-coloring conflict — with a diagnostic naming the
-      offending rank/bucket.
+      corruption — size-mismatched send, dropped ghost column,
+      round-coloring conflict — with a diagnostic naming the offending
+      rank.
   P11 Every dense-collective schedule (ring / rd / hier allreduce,
       allgatherv, reduce_scatter) verifies statically and its oracle
       equals the jnp reference (sum / concat / owned-segment) on ANY
@@ -156,35 +155,40 @@ def test_p6_costmodel_monotone(pt):
 @st.composite
 def sparse_partitions(draw):
     """A random square CSR (uneven blocks, random sparsity => random ghost
-    pattern) plus a bucket width and rng seed."""
+    pattern) plus an rng seed."""
     n_procs = draw(st.integers(1, 3))
     n = draw(st.integers(n_procs, 24))
-    bc = draw(st.sampled_from([4, 8, 16]))
     seed = draw(st.integers(0, 2 ** 16))
     rng = np.random.default_rng(seed)
     nnz = int(rng.integers(0, 4 * n))
     rows = rng.integers(0, n, size=nnz)
     cols = rng.integers(0, n, size=nnz)
     vals = rng.normal(size=nnz)
-    return CSR.from_coo(rows, cols, vals, (n, n)), n_procs, bc, seed
+    return CSR.from_coo(rows, cols, vals, (n, n)), n_procs, seed
 
 
 @settings(max_examples=15, deadline=None)
 @given(sparse_partitions())
 def test_p8_blocked_packing_matches_flat_and_ref(sp):
-    from repro.kernels.spmv_ell import spmv_ell_ref
-    from repro.kernels.spmv_ell.spmv_ell import spmv_ell, spmv_ell_blocked
+    """ELL packing through the Pallas kernel (interpret mode) and the jnp
+    oracle, and diagonal packing of the local block, against the host
+    matvec."""
+    from repro.kernels.spmv_ell import spmv_dia, spmv_ell_ref
+    from repro.kernels.spmv_ell.spmv_ell import spmv_ell
     from repro.sparse import (
+        diagonal_offsets,
         partition_csr,
+        partitioned_to_dia,
         partitioned_to_ell,
-        partitioned_to_ell_blocked,
     )
     import jax.numpy as jnp
 
-    A, n_procs, bc, seed = sp
+    A, n_procs, seed = sp
     part = partition_csr(A, n_procs)
     ell = partitioned_to_ell(part, dtype=np.float32)
-    bell = partitioned_to_ell_blocked(part, block_cols=bc, dtype=np.float32)
+    offsets = diagonal_offsets(part)
+    dia = (partitioned_to_dia(part, offsets, dtype=np.float32)
+           if offsets else None)
     plan = build_plan(part.pattern, Topology(n_procs, 1), "standard")
     rng = np.random.default_rng(seed + 1)
     x = rng.normal(size=A.ncols).astype(np.float32)
@@ -194,7 +198,7 @@ def test_p8_blocked_packing_matches_flat_and_ref(sp):
     want_all = A.matvec(x.astype(np.float64))
     for p in range(n_procs):
         n_rows = int(part.offsets[p + 1] - part.offsets[p])
-        # flat: local + ghost kernels with sentinel slots
+        # local block with its sentinel slot at in_pad
         xf = np.zeros(ell.in_pad + 1, dtype=np.float32)
         xf[: len(xs[p])] = xs[p]
         flat = spmv_ell(
@@ -205,6 +209,9 @@ def test_p8_blocked_packing_matches_flat_and_ref(sp):
             jnp.asarray(ell.local_cols[p]), jnp.asarray(ell.local_vals[p]),
             jnp.asarray(xf),
         )
+        by_dia = (spmv_dia(offsets, jnp.asarray(dia.local_vals[p]),
+                           jnp.asarray(xf[: dia.in_pad]))
+                  if dia is not None else ref)
         if ell.ghost_pad:
             gf = np.zeros(ell.ghost_pad + 1, dtype=np.float32)
             gf[: len(ghosts[p])] = ghosts[p].astype(np.float32)
@@ -220,108 +227,66 @@ def test_p8_blocked_packing_matches_flat_and_ref(sp):
                 jnp.asarray(ell.ghost_vals[p]),
                 jnp.asarray(gf), block_rows=8, interpret=True,
             ))
-            ref = ref + on_rows(spmv_ell_ref(
+            yg = on_rows(spmv_ell_ref(
                 jnp.asarray(ell.ghost_cols[p]),
                 jnp.asarray(ell.ghost_vals[p]), jnp.asarray(gf),
             ))
-        # blocked: one accumulating kernel over [local | ghost] buckets
-        xb = np.zeros(bell.x_len, dtype=np.float32)
-        xb[: len(xs[p])] = xs[p]
-        g0 = bell.n_local_buckets * bc
-        xb[g0: g0 + len(ghosts[p])] = ghosts[p].astype(np.float32)
-        blocked = spmv_ell_blocked(
-            jnp.asarray(bell.cols[p]), jnp.asarray(bell.vals[p]),
-            jnp.asarray(xb), block_cols=bc, block_rows=8, interpret=True,
-        )
-        np.testing.assert_allclose(np.asarray(blocked), np.asarray(flat),
+            ref, by_dia = ref + yg, by_dia + yg
+        np.testing.assert_allclose(np.asarray(flat), np.asarray(ref),
                                    rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(blocked), np.asarray(ref),
+        np.testing.assert_allclose(np.asarray(by_dia), np.asarray(ref),
                                    rtol=1e-5, atol=1e-6)
         want = want_all[int(part.offsets[p]): int(part.offsets[p + 1])]
-        np.testing.assert_allclose(
-            np.asarray(blocked)[:n_rows], want, rtol=1e-4, atol=1e-4
-        )
+        for y in (flat, ref, by_dia):
+            np.testing.assert_allclose(
+                np.asarray(y)[:n_rows], want, rtol=1e-4, atol=1e-4
+            )
+            np.testing.assert_array_equal(np.asarray(y)[n_rows:], 0.0)
 
 
 @settings(max_examples=15, deadline=None)
 @given(sparse_partitions())
 def test_p9_overlap_split_matches_blocked_and_host(sp):
-    from repro.kernels.spmv_ell.spmv_ell import (
-        spmv_ell_blocked,
-        spmv_ell_blocked_partial,
-        spmv_ell_blocked_skip,
-    )
-    from repro.sparse import (
-        partition_csr,
-        partitioned_to_ell_blocked,
-        row_block_bucket_map,
-    )
-    import jax.numpy as jnp
+    """The overlapped schedule equals the fused one, and both the host
+    matvec, to 1e-14 relative in f64."""
+    import jax
 
-    A, n_procs, bc, seed = sp
+    from repro.core import PlanCache
+    from repro.sparse import (
+        make_distributed_spmv,
+        pack_vector,
+        partition_csr,
+        partitioned_to_device,
+        unpack_vector,
+    )
+
+    A, n_procs, seed = sp
     part = partition_csr(A, n_procs)
-    bell = partitioned_to_ell_blocked(part, block_cols=bc, dtype=np.float32)
-    Cl, C = bell.n_local_buckets, bell.n_buckets
-    llists, lcounts = row_block_bucket_map(bell, block_rows=8, bucket_hi=Cl)
-    if C > Cl:
-        glists, gcounts = row_block_bucket_map(bell, block_rows=8,
-                                               bucket_lo=Cl)
-    plan = build_plan(part.pattern, Topology(n_procs, 1), "standard")
-    rng = np.random.default_rng(seed + 1)
-    x = rng.normal(size=A.ncols).astype(np.float32)
-    xs = [x[int(part.offsets[p]): int(part.offsets[p + 1])]
-          for p in range(n_procs)]
-    ghosts = plan.execute_numpy(xs)
-    want_all = A.matvec(x.astype(np.float64))
-    for p in range(n_procs):
-        n_rows = int(part.offsets[p + 1] - part.offsets[p])
-        cols = jnp.asarray(bell.cols[p])
-        vals = jnp.asarray(bell.vals[p])
-        xb = np.zeros(bell.x_len, dtype=np.float32)
-        xb[: len(xs[p])] = xs[p]
-        g0 = Cl * bc
-        xb[g0: g0 + len(ghosts[p])] = ghosts[p].astype(np.float32)
-        full = spmv_ell_blocked(
-            cols, vals, jnp.asarray(xb), block_cols=bc, block_rows=8,
-            interpret=True,
-        )
-        x_local, x_ghost = jnp.asarray(xb[:g0]), jnp.asarray(xb[g0:])
-        # split schedule via the carried-output partial kernel
-        y = spmv_ell_blocked_partial(
-            cols, vals, x_local, jnp.zeros((bell.row_pad,), vals.dtype),
-            bucket_lo=0, bucket_hi=Cl, n_buckets=C, block_cols=bc,
-            block_rows=8, interpret=True,
-        )
-        if C > Cl:
-            y = spmv_ell_blocked_partial(
-                cols, vals, x_ghost, y, bucket_lo=Cl, bucket_hi=C,
-                n_buckets=C, block_cols=bc, block_rows=8, interpret=True,
-            )
-        np.testing.assert_allclose(np.asarray(y), np.asarray(full),
-                                   rtol=1e-5, atol=1e-6)
-        # same split via the bucket-skipping kernel
-        ys = spmv_ell_blocked_skip(
-            cols, vals, x_local, jnp.asarray(llists[p]),
-            jnp.asarray(lcounts[p]), n_buckets=C, block_cols=bc,
-            block_rows=8, interpret=True,
-        )
-        if C > Cl:
-            ys = spmv_ell_blocked_skip(
-                cols, vals, x_ghost, jnp.asarray(glists[p]),
-                jnp.asarray(gcounts[p]), n_buckets=C, block_cols=bc,
-                bucket_base=Cl, y0=ys, block_rows=8, interpret=True,
-            )
-        np.testing.assert_allclose(np.asarray(ys), np.asarray(full),
-                                   rtol=1e-5, atol=1e-6)
-        want = want_all[int(part.offsets[p]): int(part.offsets[p + 1])]
-        np.testing.assert_allclose(
-            np.asarray(y)[:n_rows], want, rtol=1e-4, atol=1e-4
-        )
+    ell = partitioned_to_device(part)
+    mesh = jax.make_mesh((n_procs,), ("proc",),
+                         devices=jax.devices()[:n_procs])
+    exchange = None
+    if ell.ghost_pad:
+        exchange = PlanCache().collective(
+            part.pattern, Topology(n_procs, 1), "standard"
+        ).bind(mesh, "proc")
+    x = np.random.default_rng(seed + 1).normal(size=A.ncols)
+    with jax.enable_x64(True):
+        xg = pack_vector(part.col_offsets, ell.in_pad, x)
+        fused, split = (
+            np.asarray(jax.jit(make_distributed_spmv(
+                ell, mesh, "proc", exchange, overlap=ov))(xg))
+            for ov in (False, True))
+    want = A.matvec(x)
+    scale = max(np.abs(want).max(initial=0.0), 1e-300)
+    np.testing.assert_allclose(split, fused, rtol=0, atol=1e-14 * scale)
+    np.testing.assert_allclose(unpack_vector(part.offsets, split), want,
+                               rtol=0, atol=1e-14 * scale)
 
 
 # ---------------------------------------------------------------------------
 # P10: the verifier accepts everything the planners build, and rejects
-# every injected corruption with a rank/bucket diagnostic
+# every injected corruption with a rank diagnostic
 # ---------------------------------------------------------------------------
 
 
@@ -342,33 +307,28 @@ def test_p10_verifier_accepts_built_plans(pt, strategy):
 @given(sparse_partitions())
 def test_p10_verifier_accepts_built_partitions(sp):
     from repro.sparse import (
+        diagonal_offsets,
         partition_csr,
+        partitioned_to_dia,
         partitioned_to_ell,
-        partitioned_to_ell_blocked,
     )
-    from repro.sparse.device import select_spmv_kernel
     from repro.verify import (
-        verify_bucket_map,
         verify_device_ell,
-        verify_ell_blocked,
         verify_kernel_budget,
         verify_partition,
     )
 
-    A, n_procs, bc, _ = sp
+    A, n_procs, _ = sp
     part = partition_csr(A, n_procs)
     verify_partition(part)
     ell = partitioned_to_ell(part)
     verify_device_ell(ell, part)
-    verify_kernel_budget(ell, select_spmv_kernel(part))
-    bell = partitioned_to_ell_blocked(part, block_cols=bc)
-    verify_ell_blocked(bell, part)
-    verify_kernel_budget(bell, select_spmv_kernel(part, block_cols=bc))
-    verify_bucket_map(bell, block_rows=8)
-    Cl = bell.n_local_buckets
-    verify_bucket_map(bell, block_rows=8, bucket_hi=Cl)
-    if bell.n_ghost_buckets:
-        verify_bucket_map(bell, block_rows=8, bucket_lo=Cl)
+    verify_kernel_budget(ell)
+    offsets = diagonal_offsets(part)
+    if offsets:
+        dia = partitioned_to_dia(part, offsets)
+        verify_device_ell(dia, part)
+        verify_kernel_budget(dia)
 
 
 @settings(max_examples=25, deadline=None)
@@ -405,7 +365,7 @@ def test_p10_rejects_dropped_ghost_column(sp):
     from repro.sparse import partition_csr
     from repro.verify import VerifyError, verify_partition
 
-    A, n_procs, _, _ = sp
+    A, n_procs, _ = sp
     part = partition_csr(A, n_procs)
     victims = [p for p in range(n_procs) if len(part.needs[p])]
     assume(victims)
@@ -414,40 +374,6 @@ def test_p10_rejects_dropped_ghost_column(sp):
     with pytest.raises(VerifyError) as ei:
         verify_partition(part)
     assert f"rank={p}" in str(ei.value)
-
-
-@settings(max_examples=20, deadline=None)
-@given(sparse_partitions())
-def test_p10_rejects_duplicated_bucket(sp):
-    """Listing a live bucket twice in a row-block window (its values would
-    be accumulated twice by the skip kernel) must be rejected naming the
-    bucket."""
-    from hypothesis import assume
-
-    from repro.sparse import partition_csr, partitioned_to_ell_blocked
-    from repro.sparse.device import row_block_bucket_map
-    from repro.verify import VerifyError, check_bucket_map
-
-    A, n_procs, bc, _ = sp
-    assume(A.nnz > 0)
-    part = partition_csr(A, n_procs)
-    bell = partitioned_to_ell_blocked(part, block_cols=bc)
-    lists, counts = row_block_bucket_map(bell, block_rows=8)
-    # widen the list capacity by one padding column, then duplicate the
-    # last live entry of the first non-empty row block
-    lists = np.concatenate(
-        [lists, np.zeros_like(lists[:, :, :1])], axis=2
-    )
-    p, rb = np.argwhere(counts > 0)[0]
-    n = int(counts[p, rb])
-    bucket = int(lists[p, rb, n - 1])
-    lists[p, rb, n] = bucket
-    counts = counts.copy()
-    counts[p, rb] = n + 1
-    with pytest.raises(VerifyError) as ei:
-        check_bucket_map(bell, lists, counts, block_rows=8)
-    msg = str(ei.value)
-    assert f"bucket={bucket}" in msg and f"rank={p}" in msg, msg
 
 
 @settings(max_examples=25, deadline=None)

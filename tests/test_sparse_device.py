@@ -16,15 +16,12 @@ from repro.sparse import (
     pack_vector,
     partition_csr,
     partition_rect_csr,
+    partitioned_to_device,
     partitioned_to_ell,
-    partitioned_to_ell_blocked,
-    row_block_bucket_map,
-    select_spmv_kernel,
     select_spmv_overlap,
-    spmv_blocked_vmem_bytes,
-    spmv_flat_vmem_bytes,
     unpack_vector,
 )
+from repro.sparse.csr import CSR
 
 
 def _ell_matvec(cols, vals, x_ext):
@@ -100,93 +97,38 @@ def test_pack_unpack_vector_roundtrip():
     np.testing.assert_array_equal(unpack_vector(off, packed), x)
 
 
-def _blocked_matvec(bell, p, x_local, ghosts):
-    """Numpy oracle of the bucketed gather for one process block."""
-    bc = bell.block_cols
-    xcat = np.zeros(bell.x_len)
-    xcat[: len(x_local)] = x_local
-    g0 = bell.n_local_buckets * bc
-    xcat[g0: g0 + len(ghosts)] = ghosts
-    base = np.repeat(np.arange(bell.n_buckets) * bc, bell.K)
-    return np.sum(bell.vals[p] * xcat[bell.cols[p] + base[None, :]], axis=1)
+def _boundary_matrix(K: int, D: int, ncols: int = 16) -> CSR:
+    """16 x ``ncols``: row 0 holds K entries at offsets 0..K-1, rows
+    1..D-K one each in column 0 (offsets -1..-(D-K)): ELL width K, D
+    offsets."""
+    rows = [0] * K + list(range(1, D - K + 1))
+    cols = list(range(K)) + [0] * (D - K)
+    return CSR.from_coo(np.array(rows), np.array(cols),
+                        np.arange(1.0, len(rows) + 1), (16, ncols))
 
 
-def test_partitioned_to_ell_blocked_reproduces_blocks():
-    """Column-bucketed packing: per-proc blocked gather == CSR matvecs."""
-    A = diffusion_2d(16, 20)
-    n_procs = 8
-    part = partition_csr(A, n_procs)
-    bell = partitioned_to_ell_blocked(part, block_cols=16)
-    assert bell.row_pad == int(np.diff(part.offsets).max())
-    # ghost columns occupy the trailing buckets only
-    assert bell.n_ghost_buckets >= 1
-    rng = np.random.default_rng(3)
-    x = rng.normal(size=A.nrows)
-    plan = build_plan(part.pattern, Topology(n_procs, 4), "standard")
-    xs = [x[int(part.offsets[p]): int(part.offsets[p + 1])]
-          for p in range(n_procs)]
-    ghosts = plan.execute_numpy(xs)
-    for p in range(n_procs):
-        y = _blocked_matvec(bell, p, xs[p], ghosts[p])
-        want = part.local[p].matvec(xs[p])
-        if part.ghost[p].ncols:
-            want = want + part.ghost[p].matvec(ghosts[p])
-        n_rows = int(part.offsets[p + 1] - part.offsets[p])
-        np.testing.assert_allclose(y[:n_rows], want, rtol=1e-12, atol=1e-12)
-        np.testing.assert_array_equal(y[n_rows:], 0.0)
-
-
-def test_blocked_bucket_structure():
-    """In-bucket indices stay inside their bucket; local entries never land
-    in ghost buckets (and vice versa); bucket_K bounds every bucket."""
-    A = diffusion_2d(12, 12)
-    part = partition_csr(A, 4)
-    bell = partitioned_to_ell_blocked(part, block_cols=8)
-    assert np.all(bell.cols >= 0) and np.all(bell.cols < bell.block_cols)
-    assert bell.K == int(bell.bucket_K.max())
-    C, K = bell.n_buckets, bell.K
-    for p in range(4):
-        live = bell.vals[p] != 0.0
-        per_bucket = live.reshape(bell.row_pad, C, K)
-        # per-(row,bucket) live counts never exceed the recorded bucket_K
-        counts = per_bucket.sum(axis=2)
-        assert np.all(counts.max(axis=0) <= bell.bucket_K)
-
-
-def test_vmem_estimators_and_selection():
-    """Flat footprint grows with x; blocked footprint does not — and the
-    selector flips exactly at the threshold."""
-    flat_small = spmv_flat_vmem_bytes(in_pad=1000, ghost_pad=100,
-                                      k_local=9, k_ghost=4, rows=1000)
-    flat_big = spmv_flat_vmem_bytes(in_pad=2 ** 21, ghost_pad=100,
-                                    k_local=9, k_ghost=4, rows=2 ** 21)
-    assert flat_big > flat_small
-    blk_small = spmv_blocked_vmem_bytes(bucket_k=9, rows=1000)
-    blk_big = spmv_blocked_vmem_bytes(bucket_k=9, rows=2 ** 21)
-    assert blk_big <= blk_small * 2  # row-clamp only; x-length independent
-    assert flat_big > 2 ** 23 > blk_big
-
-    A = diffusion_2d(24, 24)
-    part = partition_csr(A, 4)
-    auto = select_spmv_kernel(part)
-    assert auto.variant == "flat" and not auto.forced  # tiny x: flat fits
-    blocked = select_spmv_kernel(part, vmem_limit_bytes=auto.flat_bytes - 1)
-    assert blocked.variant == "blocked" and not blocked.forced
-    at_limit = select_spmv_kernel(part, vmem_limit_bytes=auto.flat_bytes)
-    assert at_limit.variant == "flat"
-    forced = select_spmv_kernel(part, variant="blocked")
-    assert forced.variant == "blocked" and forced.forced
-    with pytest.raises(ValueError):
-        select_spmv_kernel(part, variant="banana")
-
-
-def test_vmem_limit_env_override(monkeypatch):
-    from repro.sparse import default_spmv_vmem_limit
-
-    monkeypatch.setenv("REPRO_SPMV_VMEM_LIMIT_BYTES", "12345")
-    assert default_spmv_vmem_limit() == 12345
-    monkeypatch.delenv("REPRO_SPMV_VMEM_LIMIT_BYTES")
-    assert default_spmv_vmem_limit() == 8 * 2 ** 20
+@pytest.mark.parametrize("case", ["equal", "one_more", "rectangular"])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_local_layout_rule_at_its_boundary(dtype, case):
+    """Diagonals iff the partition is square and ``D * value_bytes <= K *
+    (value_bytes + 4)``: at equality (K = 2, D = 3 in f64; K = 1, D = 2 in
+    f32) diagonal, with one offset more ELL, and the same entries over one
+    column more (a rectangular partition) ELL."""
+    vb = np.dtype(dtype).itemsize
+    K = {8: 2, 4: 1}[vb]
+    D = K * (vb + 4) // vb
+    assert D * vb == K * (vb + 4)
+    ncols = 17 if case == "rectangular" else 16
+    A = _boundary_matrix(K, D + (case == "one_more"), ncols)
+    part = partition_rect_csr(A, np.array([0, 16]), np.array([0, ncols]))
+    ell = partitioned_to_device(part, dtype)
+    assert ell.local_vals.dtype == dtype
+    if case == "equal":
+        assert ell.offsets == tuple(range(-(D - K), K))
+        assert ell.local_cols is None
+    else:
+        assert ell.offsets is None
+        assert ell.local_cols.shape == (1, 16, K)
 
 
 def test_ell_padding_points_at_sentinel():
@@ -257,36 +199,6 @@ def test_select_spmv_overlap_on_partition():
     assert solo.mode == "off"
 
 
-def test_row_block_bucket_map_structure():
-    """Lists cover exactly the live buckets of each row block, padding
-    holds bucket_lo, and the banded operator actually skips buckets."""
-    A = diffusion_2d(24, 24)
-    part = partition_csr(A, 4)
-    bell = partitioned_to_ell_blocked(part, block_cols=32)
-    C = bell.n_buckets
-    lists, counts = row_block_bucket_map(bell, block_rows=16)
-    P, nrb, M = lists.shape
-    assert P == 4 and nrb == bell.row_pad // 16
-    assert counts.shape == (P, nrb)
-    assert M == counts.max() and M < C  # banded: skipping engages
-    live = (bell.vals.reshape(P, bell.row_pad, C, bell.K) != 0).any(-1)
-    for p in range(P):
-        for rb in range(nrb):
-            want = np.flatnonzero(live[p, rb * 16: (rb + 1) * 16].any(0))
-            c = int(counts[p, rb])
-            np.testing.assert_array_equal(lists[p, rb, :c], want)
-            np.testing.assert_array_equal(lists[p, rb, c:], 0)  # bucket_lo
-    # restricted windows partition the full lists
-    Cl = bell.n_local_buckets
-    llists, lcounts = row_block_bucket_map(bell, block_rows=16, bucket_hi=Cl)
-    glists, gcounts = row_block_bucket_map(bell, block_rows=16, bucket_lo=Cl)
-    assert np.all(lcounts + gcounts == counts)
-    assert np.all(llists < Cl)
-    assert np.all(glists >= Cl)  # padding holds bucket_lo == Cl
-    with pytest.raises(AssertionError):
-        row_block_bucket_map(bell, bucket_lo=C)
-
-
 # ------------------------------------------------ diagonal local layout
 OPERATORS = {
     "rotated7": lambda: diffusion_2d(24, 24),
@@ -309,17 +221,15 @@ def test_diagonal_layout_matches_ell_gather(op, split, overlap):
     import jax
 
     from repro.core import PlanCache, Topology
-    from repro.sparse import make_distributed_spmv, partitioned_to_device
+    from repro.sparse import make_distributed_spmv
     from repro.verify import verify_device_ell
 
     A = OPERATORS[op]()
     off = np.asarray(SPLITS[split])
     P = len(off) - 1
     part = partition_rect_csr(A, off, off)
-    sel = select_spmv_kernel(part)
-    assert sel.local_layout == "diagonal"
-    assert len(sel.offsets) == (7 if op == "rotated7" else 5)
-    dia = partitioned_to_device(part, sel)
+    dia = partitioned_to_device(part)
+    assert len(dia.offsets) == (7 if op == "rotated7" else 5)
     verify_device_ell(dia, part)
     ell = partitioned_to_ell(part)
     mesh = jax.make_mesh((P,), ("proc",), devices=jax.devices()[:P])
@@ -345,7 +255,7 @@ def test_diagonal_layout_matches_ell_gather(op, split, overlap):
 @pytest.mark.parametrize("n_procs", [1, 4])
 def test_diagonal_selection_on_rotated_hierarchy(n_procs):
     """Only the stencil's fine A is banded enough for diagonals: every
-    coarse A, every R and P, and every pinned variant keep the ELL."""
+    coarse A, every R and P keep the ELL."""
     h = build_hierarchy(diffusion_2d(24, 24))
     for k, lv in enumerate(h.levels):
         ops = [("A", lv.A, k, k)]
@@ -356,14 +266,10 @@ def test_diagonal_selection_on_rotated_hierarchy(n_procs):
             cols = h.levels[kc].A.nrows
             part = partition_rect_csr(M, block_offsets(rows, n_procs),
                                       block_offsets(cols, n_procs))
-            sel = select_spmv_kernel(part)
-            want = "diagonal" if (k, name) == (0, "A") else "ell"
-            assert sel.local_layout == want, (k, name, str(sel))
-            assert sel.variant == "flat" and not sel.forced
-            assert f"local={want}" in str(sel)
-            for v in ("flat", "blocked"):
-                pinned = select_spmv_kernel(part, variant=v)
-                assert pinned.local_layout == "ell" and pinned.forced
+            ell = partitioned_to_device(part)
+            diagonal = (k, name) == (0, "A")
+            assert (ell.offsets is not None) == diagonal, (k, name)
+            assert (ell.local_cols is None) == diagonal, (k, name)
 
 
 def test_diagonal_offsets_of_rectangular_partition_is_none():
@@ -429,12 +335,12 @@ def test_compact_ghost_block_matches_row_aligned(op, split, overlap):
     import jax
 
     from repro.core import PlanCache, Topology
-    from repro.sparse import make_distributed_spmv, partitioned_to_device
+    from repro.sparse import make_distributed_spmv
     from repro.verify import verify_device_ell
 
     M, roff, coff = _compact_case(op, split)
     part = partition_rect_csr(M, roff, coff)
-    ell = partitioned_to_device(part, select_spmv_kernel(part))
+    ell = partitioned_to_device(part)
     assert ell.ghost_layout == "compact"
     Rg = ell.ghost_rows.shape[1]
     assert ell.ghost_cols.shape[:2] == (4, Rg) and 2 * Rg <= ell.row_pad
@@ -498,3 +404,69 @@ def test_ghost_block_compacts_where_at_most_half_the_rows_hold_one(
     else:
         assert ell.ghost_rows is None
         assert ell.ghost_cols.shape == (4, rows_a_block, 1)
+
+
+# ------------------------------------------------ irregular partitions
+def _irregular_case(kind: str, n_procs: int, split: str):
+    """(operator, row offsets) on 48 rows: ``random`` sparsity (ELL local
+    blocks) or a ``banded`` one (diagonal), with block 0's entries kept
+    inside its own columns, so that block has no ghost entry."""
+    n = 48
+    rng = np.random.default_rng(100 * n_procs + len(split))
+    if split == "even":
+        off = block_offsets(n, n_procs)
+    else:
+        cuts = np.sort(rng.choice(np.arange(2, n - 1), n_procs - 1,
+                                  replace=False))
+        off = np.concatenate([[0], cuts, [n]])
+    if kind == "random":
+        rows = rng.integers(0, n, size=4 * n)
+        cols = rng.integers(0, n, size=4 * n)
+    else:
+        rows = np.repeat(np.arange(n), 5)
+        cols = rows + np.tile([-7, -1, 0, 2, 5], n)
+    keep = (cols >= 0) & (cols < n) & (
+        (rows >= off[1]) | (cols < off[1]))
+    rows, cols = rows[keep], cols[keep]
+    A = CSR.from_coo(rows, cols, rng.normal(size=len(rows)), (n, n))
+    return A, np.asarray(off, dtype=np.int64)
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+@pytest.mark.parametrize("split", ["even", "uneven"])
+@pytest.mark.parametrize("n_procs", [2, 3, 4])
+@pytest.mark.parametrize("kind", ["random", "banded"])
+def test_distributed_spmv_on_irregular_partitions(kind, n_procs, split,
+                                                  overlap):
+    """The device SpMV, fused and overlapped, on random irregular row
+    blocks equals the host CSR product to 1e-14 relative in f64, with
+    one block holding no ghost entry beside blocks that hold some;
+    padded rows stay exactly zero."""
+    import jax
+
+    from repro.core import PlanCache, Topology
+    from repro.sparse import make_distributed_spmv
+    from repro.verify import verify_device_ell
+
+    A, off = _irregular_case(kind, n_procs, split)
+    part = partition_rect_csr(A, off, off)
+    ghosts = [m.nnz for m in part.ghost]
+    assert ghosts[0] == 0 and any(ghosts[1:]), ghosts
+    ell = partitioned_to_device(part)
+    assert (ell.offsets is not None) == (kind == "banded")
+    verify_device_ell(ell, part)
+    mesh = jax.make_mesh((n_procs,), ("proc",),
+                         devices=jax.devices()[:n_procs])
+    exchange = PlanCache().collective(
+        part.pattern, Topology(n_procs, 1), "standard").bind(mesh, "proc")
+    x = np.random.default_rng(7).normal(size=A.ncols)
+    with jax.enable_x64(True):
+        xg = pack_vector(off, ell.in_pad, x)
+        y = np.asarray(jax.jit(make_distributed_spmv(
+            ell, mesh, "proc", exchange, overlap=overlap))(xg))
+    assert y.dtype == np.float64
+    want = A.matvec(x)
+    np.testing.assert_allclose(unpack_vector(off, y), want, rtol=0,
+                               atol=1e-14 * np.abs(want).max())
+    for p in range(n_procs):
+        np.testing.assert_array_equal(y[p, off[p + 1] - off[p]:], 0.0)

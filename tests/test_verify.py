@@ -1,8 +1,7 @@
 """Fast tier-1 subset of the static verifier (repro.verify).
 
 Covers every pass once — pattern/plan structure + conservation, partition
-and device-ELL layout checks, bucket-map exhaustiveness, kernel VMEM
-budgets, the jaxpr audit of a bound executor, the PlanCache insertion
+and device-ELL layout checks, the kernel VMEM budget, the jaxpr audit of a bound executor, the PlanCache insertion
 hook, the canonical pattern fingerprint, ServeEngine.verify(), and the
 repo lint (self-test on seeded bugs + clean run over the tree).  The
 exhaustive randomized accept/reject coverage is hypothesis P10 in
@@ -25,19 +24,16 @@ from repro.core.costmodel import TPU_V5E
 from repro.core.neighborhood import NeighborAlltoallV
 from repro.sparse import (
     CSR,
+    diagonal_offsets,
     partition_csr,
+    partitioned_to_dia,
     partitioned_to_ell,
-    partitioned_to_ell_blocked,
 )
-from repro.sparse.device import row_block_bucket_map, select_spmv_kernel
 from repro.verify import (
     VerifyError,
     audit_executor,
-    check_bucket_map,
-    verify_bucket_map,
     verify_collective,
     verify_device_ell,
-    verify_ell_blocked,
     verify_enabled,
     verify_kernel_budget,
     verify_moe_dispatch,
@@ -137,9 +133,6 @@ def test_partition_and_layouts_accept():
     verify_partition(part)
     ell = partitioned_to_ell(part)
     verify_device_ell(ell, part)
-    bell = partitioned_to_ell_blocked(part, block_cols=8)
-    verify_ell_blocked(bell, part)
-    verify_bucket_map(bell, block_rows=8)
 
 
 def test_partition_rejects_dropped_ghost_column():
@@ -192,65 +185,41 @@ def test_compact_ghost_rows_reject_planted_fault(fault, match):
 
 def test_diagonal_layout_accepts_and_rejects_altered_nonzero():
     from repro.amg import diffusion_2d
-    from repro.sparse import diagonal_offsets, partitioned_to_dia
 
     part = partition_csr(diffusion_2d(12, 10), 3)
     dia = partitioned_to_dia(part, diagonal_offsets(part))
     verify_device_ell(dia, part)
-    verify_kernel_budget(dia, select_spmv_kernel(part))
+    verify_kernel_budget(dia)
     d, i = np.argwhere(dia.local_vals[1] != 0)[0]
     dia.local_vals[1, d, i] *= 2.0
     with pytest.raises(VerifyError, match="rank=1"):
         verify_device_ell(dia, part)
 
 
-def test_bucket_map_rejects_duplicated_bucket():
-    part = small_partition()
-    bell = partitioned_to_ell_blocked(part, block_cols=8)
-    lists, counts = row_block_bucket_map(bell, block_rows=8)
-    lists = np.concatenate([lists, np.zeros_like(lists[:, :, :1])], axis=2)
-    p, rb = np.argwhere(counts > 0)[0]
-    n = int(counts[p, rb])
-    lists[p, rb, n] = lists[p, rb, n - 1]
-    counts = counts.copy()
-    counts[p, rb] = n + 1
-    with pytest.raises(VerifyError, match="accumulated twice"):
-        check_bucket_map(bell, lists, counts, block_rows=8)
-
-
-def test_bucket_map_rejects_missing_bucket():
-    part = small_partition()
-    bell = partitioned_to_ell_blocked(part, block_cols=8)
-    lists, counts = row_block_bucket_map(bell, block_rows=8)
-    p, rb = np.argwhere(counts > 0)[0]
-    counts = counts.copy()
-    counts[p, rb] -= 1                       # hide the last live bucket
-    lists = lists.copy()
-    lists[p, rb, int(counts[p, rb])] = 0     # restore padding invariant
-    with pytest.raises(VerifyError, match="dropped"):
-        check_bucket_map(bell, lists, counts, block_rows=8)
-
-
 # ---------------------------------------------------------- kernel budgets
 
 
 def test_kernel_budget_accepts_both_layouts():
-    part = small_partition()
-    sel = select_spmv_kernel(part)
-    verify_kernel_budget(partitioned_to_ell(part), sel)
-    verify_kernel_budget(
-        partitioned_to_ell_blocked(part, block_cols=8),
-        select_spmv_kernel(part, block_cols=8),
-    )
+    """The ELL and the diagonal local layout of a stencil both fit."""
+    from repro.amg import diffusion_2d
+
+    part = partition_csr(diffusion_2d(12, 10), 3)
+    verify_kernel_budget(partitioned_to_ell(part))
+    verify_kernel_budget(partitioned_to_dia(part, diagonal_offsets(part)))
 
 
-def test_kernel_budget_rejects_underreported_selection():
-    part = small_partition()
-    bell = partitioned_to_ell_blocked(part, block_cols=8)
-    sel = select_spmv_kernel(part, block_cols=8)
-    lying = dataclasses.replace(sel, blocked_bytes=1)
-    with pytest.raises(VerifyError, match="under-reports"):
-        verify_kernel_budget(bell, lying)
+@pytest.mark.parametrize("field", ["in_pad", "ghost_pad"])
+def test_kernel_budget_rejects_footprint_over_the_core(field):
+    """An operator whose VMEM-resident x alone, local (``in_pad``) or ghost
+    (``ghost_pad``), exceeds the core's VMEM is rejected."""
+    from repro.verify.kernel_budget import vmem_bytes_per_core
+
+    ell = partitioned_to_ell(small_partition())
+    verify_kernel_budget(ell)
+    values = vmem_bytes_per_core() // 8
+    too_big = dataclasses.replace(ell, **{field: values})
+    with pytest.raises(VerifyError, match="exceeds physical VMEM"):
+        verify_kernel_budget(too_big)
 
 
 # -------------------------------------------------------------- jaxpr audit

@@ -4,10 +4,10 @@ The CI static-analysis gate: every plan producer in the repo is exercised
 with ``REPRO_VERIFY=1``, so each plan is verified on insertion into the
 ``PlanCache`` (structure + conservation + device plan), each bound
 executor is jaxpr-audited against its DevicePlan, and the hierarchy-level
-sweeps re-check partitions, ELL layouts, bucket maps and kernel budgets:
+sweeps re-check partitions, ELL layouts and kernel budgets:
 
 * ``DistributedHierarchy.setup`` — host lowering of the AMG smoke problem
-  (solve halos, R/P transfer operators, flat + blocked kernels);
+  (solve halos, R/P transfer operators, diagonal + ELL local blocks);
 * ``DistributedHierarchy.setup_partitioned`` — the distributed setup,
   whose SpGEMM gather patterns ride through the same cache;
 * ``repartition`` — the elastic rebuild onto a different device count;
@@ -21,7 +21,7 @@ sweeps re-check partitions, ELL layouts, bucket maps and kernel budgets:
   executor jaxpr-audited round-for-round against its schedule.
 
 Exit 0 with a per-producer summary, or the first ``VerifyError``
-propagates and fails the job with its rank/bucket diagnostic.
+propagates and fails the job with its rank/slot diagnostic.
 """
 import os
 
@@ -57,13 +57,6 @@ def main() -> int:
         build_hierarchy(A), mesh, procs_per_region=4, cache=cache
     )
     summary["setup"] = verify_hierarchy(dh)
-
-    # -- blocked-kernel layouts (bucket maps + budgets) on the same zoo ----
-    dh_blocked = DistributedHierarchy.setup(
-        build_hierarchy(A), mesh, procs_per_region=4, cache=cache,
-        spmv_variant="blocked", spmv_block_cols=64,
-    )
-    summary["setup_blocked"] = verify_hierarchy(dh_blocked)
 
     # -- distributed setup: SpGEMM gather patterns through the cache ------
     blocks, off = partition_fine_matrix(A, 8)
